@@ -87,9 +87,9 @@ def paged_attention_rows(key) -> None:
     for n_pages, live_frac in ((8, 0.25), (8, 0.5), (16, 0.25), (16, 0.125)):
         n_blocks = 1 + B * n_pages
         k1, k2, k3 = (jax.random.fold_in(key, 10 + i) for i in range(3))
-        kc = jax.random.normal(k1, (n_blocks, bs, Hkv, D),
+        kc = jax.random.normal(k1, (n_blocks, Hkv, bs, D),
                                jnp.float32).astype(jnp.bfloat16)
-        vc = jax.random.normal(k2, (n_blocks, bs, Hkv, D),
+        vc = jax.random.normal(k2, (n_blocks, Hkv, bs, D),
                                jnp.float32).astype(jnp.bfloat16)
         q = jax.random.normal(k3, (B, Hkv, G, D),
                               jnp.float32).astype(jnp.bfloat16)

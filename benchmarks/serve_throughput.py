@@ -60,6 +60,7 @@ import os
 import subprocess
 import sys
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -711,7 +712,14 @@ def mesh_leg_economics(args):
     engine inside the subprocess. On forced-host CPU devices all "devices"
     share one physical CPU, so efficiency well below 1 is expected — the
     leg exists so the trajectory is tracked where real accelerators will
-    make it meaningful."""
+    make it meaningful. CPU only: the child forces host devices, and on a
+    chip machine it would contend for the chip the parent holds — the
+    chip's mesh check is ``chip_smoke.py --four-chips``."""
+    if jax.default_backend() != "cpu":
+        raise SystemExit(
+            f"the serve_throughput mesh leg runs on forced CPU host devices "
+            f"and refuses backend {jax.default_backend()!r}; run "
+            f"`python chip_smoke.py --four-chips` for the chip's mesh check")
     cfg = {"requests": min(args.requests, 4), "n_slots": args.n_slots,
            "prompt_len": min(args.prompt_len, 16),
            "new_tokens": min(args.new_tokens, 8),

@@ -360,13 +360,16 @@ def calibrate(model, params, calib_batches: Optional[Iterable],
               gain_models: Optional[dict] = None,
               sens: Optional[SensitivityResult] = None,
               loss_fn: Optional[Callable] = None,
-              cache: Optional[str] = None) -> CalibrationBundle:
+              cache: Optional[str] = None,
+              op_chunk: Optional[int] = None) -> CalibrationBundle:
     """The expensive phase of Algorithm 1, run once per (model, params).
 
     Stages: (1) sensitivity calibration over ``calib_batches`` — skipped when
-    a precomputed ``sens`` is injected; (2) partition into sequential
-    sub-graphs; (3) per-group gain tables for every model in ``gain_models``
-    (default: the Sec. 2.3 registry — ET roofline, TT theoretical, M memory).
+    a precomputed ``sens`` is injected, probe memory bounded by ``op_chunk``
+    (see :func:`~repro.core.sensitivity.calibrate_sensitivity`); (2)
+    partition into sequential sub-graphs; (3) per-group gain tables for
+    every model in ``gain_models`` (default: the Sec. 2.3 registry — ET
+    roofline, TT theoretical, M memory).
 
     ``cache``: path of a saved bundle. If it exists and matches (same
     formats, partition options, params fingerprint, and objectives), it is
@@ -398,7 +401,8 @@ def calibrate(model, params, calib_batches: Optional[Iterable],
 
     # ---- Alg.1 line 2: sensitivity calibration ----
     if sens is None:
-        sens = calibrate_sensitivity(loss_fn, params, calib_batches)
+        sens = calibrate_sensitivity(loss_fn, params, calib_batches,
+                                     op_chunk=op_chunk)
     op_index = {op.name: op for op in sens.ops}
 
     # ---- Alg.1 line 1: partition (once; filtered per objective) ----

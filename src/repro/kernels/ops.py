@@ -1,8 +1,11 @@
 """Jit'd high-level wrappers around the Pallas kernels.
 
-``interpret`` defaults to True off-TPU so the same call sites work in this
-CPU container (kernel bodies execute in Python) and compile to Mosaic on a
-real TPU. Shapes are padded to block multiples here, never inside kernels.
+``interpret`` defaults to True on the CPU backend, so the same call sites
+run in CPU tests (kernel bodies execute through the Pallas interpreter) and
+compile to Mosaic on a TPU. Any other backend is an error: interpreting a
+kernel on an accelerator would hide compile refusals and time the
+interpreter. Shapes are padded to block multiples here, never inside
+kernels.
 """
 from __future__ import annotations
 
@@ -22,7 +25,11 @@ __all__ = ["default_interpret", "fp8_linear", "quantize_fp8",
 
 @functools.cache
 def default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend not in ("cpu", "tpu"):
+        raise RuntimeError(f"Pallas kernels compile for TPU and interpret on "
+                           f"CPU; backend {backend!r} is neither")
+    return backend == "cpu"
 
 
 def _pad_to(x: jax.Array, mults: tuple) -> jax.Array:

@@ -14,10 +14,12 @@ Layout contract (mirrors ``nn/layers.py`` paged caches):
 
 * ``q``: (B, Hkv, G, Dk) — one query token per row, GQA via head-group
   reshape (H = Hkv * G). MLA absorbed decode passes Hkv=1, G=H.
-* ``k``/``v``: (n_blocks, block_size, Hkv, D) block-major physical storage.
-  ``v=None`` reuses ``k`` as values (MLA: both scores and context contract
-  the latent ``ckv``). ``q2``/``k2`` optionally add a second score operand
-  (MLA RoPE part): ``s = q @ k^T + q2 @ k2^T``.
+* ``k``/``v``: (n_blocks, Hkv, block_size, D) head-major physical storage,
+  so each grid step's block ``(1, 1, block_size, D)`` is one head's page
+  and its last two dims tile on the TPU. ``v=None`` reuses ``k`` as values
+  (MLA: both scores and context contract the latent ``ckv``). ``q2``/``k2``
+  optionally add a second score operand (MLA RoPE part):
+  ``s = q @ k^T + q2 @ k2^T``.
 * ``block_tables``: (B, max_blocks) int32, -1 = unallocated. Dead pages are
   clamped to the trash block 0 *in the index map*, so consecutive dead pages
   revisit the same block and the pipeline elides their copies — a row costs
@@ -40,6 +42,17 @@ attention; None = keep f32, the MLA absorbed path). Each operand fetches a
 live block only in the phase that consumes it (K in phase 0, V in phase 1
 — both once per live block); the MLA ``v=None`` path reads its ``ckv``
 blocks in both phases because keys and values share that storage.
+
+Score scratch: token-major ``(max_blocks * block_size, G)`` f32. A page's
+scores are rows ``[j*bs, (j+1)*bs)`` — a sublane offset, aligned whenever
+``block_size`` is a multiple of 8, which the TPU compiler needs (a lane
+offset of ``j*bs`` is not provably 128-aligned and is refused). The
+denominator reduces the scratch over its leading axis, which on the CPU
+backend sums in the reference's order. Each token pads ``G`` (<= 128) to a
+128-lane row, so the scratch costs 512 B per token of ``max_len``: on a TPU
+v5e under the default 16 MiB scoped-VMEM limit the kernel compiles at
+``max_len`` 30720 and is refused at 32768 (B=8, Hkv=8, G=4, D=64,
+block 16). Longer contexts need the single-pass softmax of ROADMAP A3.
 """
 from __future__ import annotations
 
@@ -51,7 +64,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams
 
 __all__ = ["paged_decode_attention", "BIG_WINDOW"]
 
@@ -78,6 +90,7 @@ def _kernel(bt_ref, len_ref, win_ref, q_ref, *rest, bs: int, n_pages: int,
     ln = len_ref[b]
     win = win_ref[0]
     start = j * bs
+    rows = pl.ds(pl.multiple_of(start, bs), bs)   # this page's score rows
     # any key of this page both causally live and inside the window?
     page_live = (start < ln) & (start + bs > ln - win)
 
@@ -89,36 +102,36 @@ def _kernel(bt_ref, len_ref, win_ref, q_ref, *rest, bs: int, n_pages: int,
     @pl.when((ph == 0) & page_live)
     def _scores():
         q = q_ref[0, 0]                                   # (G, Dk)
-        k = _dequant(k_ref[0, :, 0, :], q.dtype, k_scale)  # (bs, Dk)
+        k = _dequant(k_ref[0, 0], q.dtype, k_scale)       # (bs, Dk)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         if has_k2:
             q2 = q2_ref[0, 0]
-            k2 = _dequant(k2_ref[0, :, 0, :], q2.dtype, k_scale)
+            k2 = _dequant(k2_ref[0, 0], q2.dtype, k_scale)
             s = s + jax.lax.dot_general(q2, k2, (((1,), (1,)), ((), ())),
                                         preferred_element_type=jnp.float32)
         if score_dtype is not None:   # reference rounds scores (bf16 GQA)
             s = s.astype(score_dtype)
-        s = s.astype(jnp.float32)
+        s = s.astype(jnp.float32).T                       # (bs, G)
         s = s / scale if scale_mode == "div" else s * scale
-        kpos = start + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
+        kpos = start + jax.lax.broadcasted_iota(jnp.int32, (bs, 1), 0)
         live = (kpos < ln) & (kpos > ln - 1 - win)
         s = jnp.where(live, s, NEG)
-        s_ref[:, pl.ds(start, bs)] = s
-        m_ref[...] = jnp.maximum(m_ref[...], jnp.max(s, -1, keepdims=True))
+        s_ref[rows, :] = s
+        m_ref[...] = jnp.maximum(m_ref[...], jnp.max(s, 0, keepdims=True))
 
     @pl.when((ph == 1) & (j == 0))
     def _denominator():
-        l_ref[...] = jnp.sum(jnp.exp(s_ref[...] - m_ref[...]), -1,
+        l_ref[...] = jnp.sum(jnp.exp(s_ref[...] - m_ref[...]), 0,
                              keepdims=True)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     @pl.when((ph == 1) & page_live)
     def _context():
-        p = jnp.exp(s_ref[:, pl.ds(start, bs)] - m_ref[...]) / l_ref[...]
+        p = (jnp.exp(s_ref[rows, :] - m_ref[...]) / l_ref[...]).T  # (G, bs)
         if probs_dtype is not None:   # reference rounds probs (bf16 GQA)
             p = p.astype(probs_dtype)
-        v = _dequant(v_ref[0, :, 0, :], p.dtype, v_scale)  # (bs, Dv)
+        v = _dequant(v_ref[0, 0], p.dtype, v_scale)       # (bs, Dv)
         acc_ref[...] += jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -145,7 +158,7 @@ def _call(q, k, v, q2, k2, block_tables, lengths, window, *, scale,
           scale_mode, score_dtype, probs_dtype, k_scale, v_scale,
           out_dtype, interpret):
     B, Hkv, G, Dk = q.shape
-    bs = k.shape[1]
+    bs = k.shape[2]
     n_pages = block_tables.shape[1]
     v_from_k = v is None
     Dv = k.shape[-1] if v_from_k else v.shape[-1]
@@ -157,22 +170,22 @@ def _call(q, k, v, q2, k2, block_tables, lengths, window, *, scale,
         def index(b, h, ph, j, bt, ln, wn):
             live = ((j * bs < ln[b]) & (j * bs + bs > ln[b] - wn[0])
                     & ((ph == 0) & keep0 | (ph == 1) & keep1))
-            return (jnp.where(live, jnp.maximum(bt[b, j], 0), 0), 0, h, 0)
+            return (jnp.where(live, jnp.maximum(bt[b, j], 0), 0), h, 0, 0)
         return index
 
     def q_map(b, h, ph, j, *_):
         return (b, h, 0, 0)
 
     in_specs = [pl.BlockSpec((1, 1, G, Dk), q_map),
-                pl.BlockSpec((1, bs, 1, Dk), kv_map(True, v_from_k))]
+                pl.BlockSpec((1, 1, bs, Dk), kv_map(True, v_from_k))]
     operands = [q, k]
     if has_k2:
         in_specs += [pl.BlockSpec((1, 1, G, q2.shape[-1]), q_map),
-                     pl.BlockSpec((1, bs, 1, k2.shape[-1]),
+                     pl.BlockSpec((1, 1, bs, k2.shape[-1]),
                                   kv_map(True, False))]
         operands += [q2, k2]
     if not v_from_k:
-        in_specs.append(pl.BlockSpec((1, bs, 1, Dv), kv_map(False, True)))
+        in_specs.append(pl.BlockSpec((1, 1, bs, Dv), kv_map(False, True)))
         operands.append(v)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -181,9 +194,9 @@ def _call(q, k, v, q2, k2, block_tables, lengths, window, *, scale,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, 1, G, Dv), q_map),
         scratch_shapes=[
-            pltpu.VMEM((G, 1), jnp.float32),            # running max
-            pltpu.VMEM((G, 1), jnp.float32),            # denominator
-            pltpu.VMEM((G, n_pages * bs), jnp.float32),  # masked scores
+            pltpu.VMEM((1, G), jnp.float32),            # running max
+            pltpu.VMEM((1, G), jnp.float32),            # denominator
+            pltpu.VMEM((n_pages * bs, G), jnp.float32),  # masked scores
             pltpu.VMEM((G, Dv), jnp.float32),            # output accumulator
         ],
     )
@@ -194,7 +207,7 @@ def _call(q, k, v, q2, k2, block_tables, lengths, window, *, scale,
                           v_scale=v_scale, has_k2=has_k2, v_from_k=v_from_k),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, G, Dv), out_dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary",
                                  "arbitrary")),
         interpret=interpret,
@@ -216,10 +229,11 @@ def paged_decode_attention(q: jax.Array, k: jax.Array, v: Optional[jax.Array],
     scalar (scan-mode per-layer windows); ``scale_mode`` selects
     ``s / scale`` (GQA reference) vs ``s * scale`` (MLA absorbed reference).
     Rows whose ``lengths`` entry is 0 produce zeros. ``interpret`` defaults
-    to True off-TPU so the same call site runs in CPU CI and compiles to
-    Mosaic on a real TPU. On TPU, fp8 caches want ``block_size`` >= the fp8
-    min sublane tile (32); smaller blocks still compile via Mosaic padding
-    but waste tile bandwidth.
+    to True on the CPU backend so the same call site runs in CPU CI and
+    compiles to Mosaic on a TPU. On TPU, ``block_size`` must be a multiple
+    of 8; fp8 caches want ``block_size`` >= the fp8 min sublane tile (32);
+    smaller blocks still compile via Mosaic padding but waste tile
+    bandwidth.
     """
     if interpret is None:
         from repro.kernels.ops import default_interpret

@@ -21,7 +21,10 @@ __all__ = ["amax", "scale_cast", "quantize_fp8"]
 
 
 def _amax_kernel(x_ref, o_ref):
-    o_ref[0, 0] = jnp.max(jnp.abs(x_ref[...].astype(jnp.float32)))
+    # one whole (8, 128) f32 tile per row block: a (1, 1) output block is
+    # refused by the TPU compiler (last two block dims must tile)
+    o_ref[...] = jnp.full(o_ref.shape, jnp.max(
+        jnp.abs(x_ref[...].astype(jnp.float32))), jnp.float32)
 
 
 def _cast_kernel(x_ref, s_ref, o_ref):
@@ -39,8 +42,8 @@ def amax(x: jax.Array, *, block_m: int = 256, interpret: bool = False):
         _amax_kernel,
         grid=grid,
         in_specs=[pl.BlockSpec((bm, N), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((1, 1), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((grid[0], 1), jnp.float32),
+        out_specs=pl.BlockSpec((1, 8, 128), lambda i: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((grid[0], 8, 128), jnp.float32),
         interpret=interpret,
     )(x)
     return jnp.max(partial)

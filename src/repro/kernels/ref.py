@@ -27,10 +27,10 @@ def scale_cast_ref(x: jax.Array, scale, dtype=jnp.float8_e4m3fn) -> jax.Array:
 
 def _paged_deq(cache, block_tables, dtype, scale):
     """Gather-to-logical-order dequant (the ``paged_gather`` semantics)."""
-    bs = cache.shape[1]
+    n_blocks, H, bs, D = cache.shape           # head-major pages
     B, npg = block_tables.shape
     g = jnp.take(cache, jnp.maximum(block_tables, 0), axis=0)
-    g = g.reshape(B, npg * bs, *cache.shape[2:])
+    g = jnp.transpose(g, (0, 1, 3, 2, 4)).reshape(B, npg * bs, H, D)
     if scale != 1.0:
         return (g.astype(jnp.float32) * scale).astype(dtype)
     return g.astype(dtype)

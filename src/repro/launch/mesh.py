@@ -7,6 +7,7 @@ importing this module touches no JAX device state. Single pod = 256 chips
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 __all__ = ["make_production_mesh", "make_local_mesh", "parse_mesh_spec",
            "mesh_from_spec"]
@@ -22,7 +23,8 @@ def make_production_mesh(*, multi_pod: bool = False):
     assert len(devs) >= need, (
         f"need {need} devices (set XLA_FLAGS=--xla_force_host_platform_"
         f"device_count=512 before importing jax); have {len(devs)}")
-    return jax.make_mesh(shape, axes, devices=devs[:need])
+    return jax.make_mesh(shape, axes, devices=devs[:need],
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_local_mesh(data: int = 1, model: int = 1):
@@ -30,7 +32,8 @@ def make_local_mesh(data: int = 1, model: int = 1):
     n = len(jax.devices())
     assert data * model <= n, f"need {data * model} devices, have {n}"
     return jax.make_mesh((data, model), ("data", "model"),
-                         devices=jax.devices()[:data * model])
+                         devices=jax.devices()[:data * model],
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def parse_mesh_spec(spec: str) -> dict:

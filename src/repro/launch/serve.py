@@ -32,6 +32,7 @@ import numpy as np
 from repro.checkpoint import CheckpointManager
 from repro.core.mpconfig import MPPlan
 from repro.core.pipeline import CalibrationBundle
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.registry import get_model
 from repro.serve import ContinuousBatchingEngine, Request, ServeEngine
 
@@ -201,6 +202,10 @@ def main():
                          "token transfer with decode)")
     args = ap.parse_args()
 
+    cache_dir = enable_compile_cache()
+    dev = jax.devices()[0]
+    print(f"[serve] device: {dev.platform} {dev.device_kind} x "
+          f"{len(jax.devices())} | compile cache {cache_dir}")
     model = get_model(args.arch, smoke=args.smoke)
     if args.ckpt_dir:
         step, tree, _ = CheckpointManager(args.ckpt_dir).restore()

@@ -16,6 +16,7 @@ import argparse
 import jax
 
 from repro.data.synthetic import SyntheticConfig, SyntheticLM
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh
 from repro.models.registry import get_model
 from repro.train.optim import OptConfig, select_optimizer
@@ -39,6 +40,7 @@ def main():
     ap.add_argument("--abort-on-straggler", action="store_true")
     args = ap.parse_args()
 
+    enable_compile_cache()
     model = get_model(args.arch, smoke=args.smoke)
     print(f"[train] arch={args.arch} params={model.n_params():,} "
           f"mesh=({args.mesh_data},{args.mesh_model})")

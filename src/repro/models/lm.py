@@ -596,8 +596,9 @@ class LM:
     def paged_cache_specs(self, n_slots: int, n_blocks: int,
                           block_size: int) -> dict:
         """Flat specs for paged serving: attention K/V (and MLA latents) are
-        block-major ``(n_blocks, block_size, ...)`` shared storage; SSM state
-        has no sequence axis and stays slot-major ``(n_slots, ...)``."""
+        head-major ``(n_blocks, heads, block_size, width)`` shared storage;
+        SSM state has no sequence axis and stays slot-major
+        ``(n_slots, ...)``."""
         cfg = self.cfg
         kv_dtype = self._kv_dtype
 
@@ -682,12 +683,12 @@ class LM:
         """Scatter a freshly prefilled batch=1 dense cache into paged storage.
 
         Page-major leaves (attention K/V, MLA latents) land in the physical
-        blocks named by ``block_ids``; the dense prefill length must equal
-        ``len(block_ids) * block_size`` so the reshape is exact. Slot-major
-        leaves (SSM state) overwrite row ``slot``. The dense ``pos`` ring is
-        dropped: paged attention derives key positions from block-table
-        order. Pure function of its array args — jit it once per distinct
-        prompt-block count.
+        blocks named by ``block_ids``, head-major; the dense prefill length
+        must equal ``len(block_ids) * block_size`` so the reshape is exact.
+        Slot-major leaves (SSM state) overwrite row ``slot``. The dense
+        ``pos`` ring is dropped: paged attention derives key positions from
+        block-table order. Pure function of its array args — jit it once
+        per distinct prompt-block count.
         """
         scan = self.cfg.scan_layers
         nb = block_ids.shape[0]
@@ -698,17 +699,14 @@ class LM:
                 if "pos" in dv and "pos" not in pv:    # attention page node
                     out = {}
                     for name, leaf in pv.items():
-                        src = dv[name]
-                        if scan:
-                            n_l, bs = leaf.shape[0], leaf.shape[2]
-                            s = src[:, 0].reshape((n_l, nb, bs) + src.shape[3:])
-                            out[name] = leaf.at[:, block_ids].set(
-                                s.astype(leaf.dtype))
-                        else:
-                            bs = leaf.shape[1]
-                            s = src[0].reshape((nb, bs) + src.shape[2:])
-                            out[name] = leaf.at[block_ids].set(
-                                s.astype(leaf.dtype))
+                        # dense (S, [heads,] width) -> (nb, heads, bs, width)
+                        src = dv[name][:, 0] if scan else dv[name][0]
+                        lead = src.shape[:1] if scan else ()
+                        h, bs, w = leaf.shape[-3:]
+                        s = src.reshape(lead + (nb, bs, h, w)).swapaxes(-3, -2)
+                        out[name] = (leaf.at[:, block_ids] if scan
+                                     else leaf.at[block_ids]).set(
+                                         s.astype(leaf.dtype))
                     return out
                 return {k: rec(v, dv[k]) for k, v in pv.items()}
             # slot-major leaf (SSM state): overwrite row ``slot``

@@ -16,8 +16,10 @@ KV cache — two layouts share the attention math:
   O(window) decode memory (what makes hymba ``long_500k`` deployable);
   full-attention archs use W = max_len where the ring write degenerates to
   an append.
-* paged blocks (continuous serving): ``{"k": (n_blocks, block_size, Hkv,
-  D), "v": ...}`` — physical blocks owned by a ``PagedCachePool``; each
+* paged blocks (continuous serving): ``{"k": (n_blocks, Hkv, block_size,
+  D), "v": ...}`` — head-major physical blocks owned by a
+  ``PagedCachePool`` (MLA latents use one head: ``(n_blocks, 1,
+  block_size, width)``; every page leaf has this 4-D layout); each
   decode row carries a block table (row of physical block ids, -1 =
   unallocated) and logical position ``j*block_size + i`` lives at page-table
   entry ``j``, offset ``i``. There is no ``pos`` leaf: the pool guarantees
@@ -229,13 +231,15 @@ def kv_page_spec(cfg: AttnConfig, n_blocks: int, block_size: int,
     The leading block dim carries the ``kv_blocks`` logical axis: under a
     serving mesh the physical pool is device-sharded over ``data`` (each
     shard owns a contiguous page range, see ``PagedCachePool``), falling
-    back to replication when ``n_blocks`` doesn't divide."""
+    back to replication when ``n_blocks`` doesn't divide. Pages are
+    head-major ``(n_blocks, Hkv, block_size, D)``: the fused kernel fetches
+    one head's page as a ``(block_size, D)`` tile."""
     return {
-        "k": ParamSpec((n_blocks, block_size, cfg.n_kv_heads, cfg.d_head),
-                       ("kv_blocks", None, "kv_heads", "head_dim"), dtype,
+        "k": ParamSpec((n_blocks, cfg.n_kv_heads, block_size, cfg.d_head),
+                       ("kv_blocks", "kv_heads", None, "head_dim"), dtype,
                        "zeros"),
-        "v": ParamSpec((n_blocks, block_size, cfg.n_kv_heads, cfg.d_head),
-                       ("kv_blocks", None, "kv_heads", "head_dim"), dtype,
+        "v": ParamSpec((n_blocks, cfg.n_kv_heads, block_size, cfg.d_head),
+                       ("kv_blocks", "kv_heads", None, "head_dim"), dtype,
                        "zeros"),
     }
 
@@ -253,8 +257,9 @@ def paged_write_chunk(cache: dict, tensors: dict, block_tables: jax.Array,
     copy-on-write forked before they enter any write range) and writes
     contiguously. Chunks may start mid-sequence against a pre-populated
     table — resumed prefills and prefix-cache tail chunks rely on this.
+    ``tensors`` are (B, T, heads, width), matching the head-major pages.
     """
-    bs = next(iter(cache.values())).shape[1]
+    bs = next(iter(cache.values())).shape[2]
     nb = block_tables.shape[1]
     page_idx = jnp.clip(positions // bs, 0, nb - 1)
     page = jnp.take_along_axis(block_tables, page_idx, axis=1)     # (B, T)
@@ -262,7 +267,8 @@ def paged_write_chunk(cache: dict, tensors: dict, block_tables: jax.Array,
     off = positions % bs
     new = dict(cache)
     for name, t in tensors.items():
-        new[name] = cache[name].at[page, off].set(t.astype(cache[name].dtype))
+        new[name] = cache[name].at[page, :, off].set(
+            t.astype(cache[name].dtype))
     return new
 
 
@@ -274,7 +280,7 @@ def paged_write(cache: dict, tensors: dict, block_tables: jax.Array,
     (B,) absolute write positions. Rows with an unallocated page (table entry
     -1, e.g. vacant slots) are clamped to the trash block 0.
     """
-    bs = next(iter(cache.values())).shape[1]
+    bs = next(iter(cache.values())).shape[2]
     B = block_tables.shape[0]
     cp = jnp.broadcast_to(jnp.asarray(cache_pos, jnp.int32), (B,))
     page = jnp.take_along_axis(block_tables, (cp // bs)[:, None], axis=1)[:, 0]
@@ -282,27 +288,31 @@ def paged_write(cache: dict, tensors: dict, block_tables: jax.Array,
     off = cp % bs
     new = dict(cache)
     for name, t in tensors.items():
-        new[name] = cache[name].at[page, off].set(t[:, 0].astype(cache[name].dtype))
+        new[name] = cache[name].at[page, :, off].set(
+            t[:, 0].astype(cache[name].dtype))
     return new
 
 
 def paged_gather(cache: dict, block_tables: jax.Array, dtype,
                  scales: Optional[dict] = None) -> tuple:
     """Gather each row's blocks into logical order: (B, S, ...) tensors plus
-    the (B, S) logical key positions (S = max_blocks * block_size). Entries
-    beyond a row's written length read stale/trash data; they sit at logical
-    positions > the row's query position, so the causal mask removes them.
+    the (B, S) logical key positions (S = max_blocks * block_size); each
+    (n_blocks, heads, block_size, width) leaf comes back as
+    (B, S, heads, width). Entries beyond a row's written length read
+    stale/trash data; they sit at logical positions > the row's query
+    position, so the causal mask removes them.
 
     ``scales`` maps cache-entry names to per-tensor dequant multipliers,
     applied with exactly the fused kernel's ``_dequant`` semantics (f32
     multiply, cast to ``dtype``; a 1.0 scale is a plain upcast so the
     unscaled path stays bit-identical to the legacy gather)."""
-    bs = next(iter(cache.values())).shape[1]
+    bs = next(iter(cache.values())).shape[2]
     B, nb = block_tables.shape
     bt = jnp.maximum(block_tables, 0)
 
     def deq(name, arr):
-        g = jnp.take(arr, bt, axis=0).reshape(B, nb * bs, *arr.shape[2:])
+        g = jnp.take(arr, bt, axis=0).swapaxes(2, 3)   # (B, nb, bs, H, D)
+        g = g.reshape(B, nb * bs, arr.shape[1], arr.shape[3])
         s = 1.0 if scales is None else float(scales.get(name, 1.0))
         if s == 1.0:
             return g.astype(dtype)
@@ -371,11 +381,10 @@ def _paged_kernel_call(qk: jax.Array, k_pages: jax.Array, v_pages,
         return paged_decode_attention(qk, k_pages, v_pages, block_tables,
                                       lengths, window=window, q2=q2, k2=k2,
                                       **kw)
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     q_spec = P("data", "model", None, None)
-    page_spec = P("data" if layout.shard_pages else None, None, "model", None)
+    page_spec = P("data" if layout.shard_pages else None, "model", None, None)
     operands = [qk, k_pages, block_tables, lengths]
     specs = [q_spec, page_spec, P("data", None), P("data")]
     has_v = v_pages is not None
@@ -403,9 +412,9 @@ def _paged_kernel_call(qk: jax.Array, k_pages: jax.Array, v_pages,
         return paged_decode_attention(qk_, pages_, vp, bt_, len_, window=w,
                                       q2=q2_, k2=k2_, **kw)
 
-    return shard_map(body, mesh=layout.mesh, in_specs=tuple(specs),
-                     out_specs=P("data", "model", None, None),
-                     check_rep=False)(*operands)
+    return jax.shard_map(body, mesh=layout.mesh, in_specs=tuple(specs),
+                         out_specs=P("data", "model", None, None),
+                         check_vma=False)(*operands)
 
 
 def paged_update_attend(cache: dict, tensors: dict, block_tables: jax.Array,
@@ -865,12 +874,13 @@ def mla_cache_spec(cfg: MLAConfig, batch: int, max_len: int,
 
 def mla_page_spec(cfg: MLAConfig, n_blocks: int, block_size: int,
                   dtype=jnp.bfloat16) -> dict:
-    """Paged latent KV storage (see :func:`kv_page_spec` for semantics)."""
+    """Paged latent KV storage (see :func:`kv_page_spec` for semantics):
+    one shared latent "head", ``(n_blocks, 1, block_size, width)``."""
     return {
-        "ckv": ParamSpec((n_blocks, block_size, cfg.kv_lora_rank),
-                         ("kv_blocks", None, "kv_lora"), dtype, "zeros"),
-        "kr": ParamSpec((n_blocks, block_size, cfg.qk_rope_dim),
-                        ("kv_blocks", None, None), dtype, "zeros"),
+        "ckv": ParamSpec((n_blocks, 1, block_size, cfg.kv_lora_rank),
+                         ("kv_blocks", None, None, "kv_lora"), dtype, "zeros"),
+        "kr": ParamSpec((n_blocks, 1, block_size, cfg.qk_rope_dim),
+                        ("kv_blocks", None, None, None), dtype, "zeros"),
     }
 
 
@@ -924,13 +934,14 @@ def mla_attention(p: dict, ctx: QuantContext, scope: str, cfg: MLAConfig,
                  and use_fused_paged(ctx, scope, paged_attn)
                  and _mesh_fused_ok(B, 1))
         new_cache, g, kp = paged_update_attend(
-            cache, {"ckv": ckv, "kr": kr}, block_tables, positions,
-            cache_pos, chunk_valid, x.dtype, fused=fused, scales=kv_scales)
+            cache, {"ckv": ckv[:, :, None], "kr": kr[:, :, None]},
+            block_tables, positions, cache_pos, chunk_valid, x.dtype,
+            fused=fused, scales=kv_scales)
         if g is None:
             return _mla_decode_absorbed_paged(p, ctx, scope, cfg, qn, qr,
                                               new_cache, block_tables,
                                               positions, scales=kv_scales)
-        ckv, kr = g["ckv"], g["kr"]
+        ckv, kr = g["ckv"][:, :, 0], g["kr"][:, :, 0]
         if chunk_valid is None and cfg.absorb_decode:
             return _mla_decode_absorbed(p, ctx, scope, cfg, qn, qr, ckv,
                                         kr, positions, kp, new_cache)
@@ -1064,10 +1075,10 @@ def _mla_decode_absorbed_paged(p, ctx, scope, cfg: MLAConfig, qn, qr,
             f"kv_dequant_scales (got {sc}); use paged_attn='gather'")
     ctx_lat = _paged_kernel_call(
         q_lat.reshape(B, 1, H, r),                      # (B, Hkv=1, G=H, r)
-        new_cache["ckv"][:, :, None, :], None,          # v = ckv (latent)
+        new_cache["ckv"], None,                         # v = ckv (latent)
         block_tables, lengths,
         q2=qr.astype(jnp.float32).reshape(B, 1, H, cfg.qk_rope_dim),
-        k2=new_cache["kr"][:, :, None, :],
+        k2=new_cache["kr"],
         scale=1.0 / _math.sqrt(dn + cfg.qk_rope_dim), scale_mode="mul",
         k_scale=1.0, v_scale=1.0,  # non-unit scales rejected above
         out_dtype=jnp.float32)

@@ -7,11 +7,11 @@ with ``n_slots * max_len`` regardless of live tokens.
 
 :class:`PagedCachePool` (vLLM-style) replaces the per-slot rings with a
 shared store of fixed-size blocks: attention K/V lives in block-major arrays
-``(n_blocks, block_size, ...)``, each slot maps its logical pages to physical
-blocks through a host-side block table, and blocks are allocated on demand as
-decode crosses block boundaries — HBM scales with *live tokens*, so an MP
-plan's fp8 ``kv_cache_dtype`` savings buy proportionally more concurrent
-slots. Admission reserves a request's worst-case block count (prompt +
+``(n_blocks, heads, block_size, width)``, each slot maps its logical pages
+to physical blocks through a host-side block table, and blocks are
+allocated on demand as decode crosses block boundaries — HBM scales with
+*live tokens*, so an MP plan's fp8 ``kv_cache_dtype`` savings buy
+proportionally more concurrent slots. Admission reserves a request's worst-case block count (prompt +
 ``max_new_tokens - 1`` writes), which makes mid-decode allocation infallible
 while materializing blocks lazily; :meth:`can_admit` returning False is the
 scheduler's backpressure signal. Physical block 0 is never allocated: it is
@@ -50,8 +50,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-__all__ = ["CachePool", "PagedCachePool", "dense_slot_bytes",
-           "paged_block_bytes", "paged_slot_bytes"]
+__all__ = ["CachePool", "PagedCachePool", "PoolExhausted",
+           "dense_slot_bytes", "paged_block_bytes", "paged_slot_bytes"]
 
 
 def dense_slot_bytes(model, max_len: int) -> int:
@@ -85,6 +85,13 @@ def _scatter_slot(pool: dict, one: dict, slot: jax.Array) -> dict:
         start = (slot,) + (0,) * (pl.ndim - 1)
         return jax.lax.dynamic_update_slice(pl, ol.astype(pl.dtype), start)
     return jax.tree.map(upd, pool, one)
+
+
+class PoolExhausted(RuntimeError):
+    """No free slot or block for this request right now: admission
+    backpressure, not a device fault. Engines catch exactly this — a
+    ``jax.errors.JaxRuntimeError`` (device OOM, a failed compile) is also a
+    ``RuntimeError`` and must propagate, not retire requests as failed."""
 
 
 # one jitted copy-on-write fork per (model, layout): pools are rebuilt per
@@ -131,9 +138,10 @@ class CachePool:
         return len(self._free)
 
     def alloc(self) -> int:
-        """Claim a free slot index; raises RuntimeError when the pool is full."""
+        """Claim a free slot index; raises PoolExhausted when the pool is
+        full."""
         if not self._free:
-            raise RuntimeError("cache pool exhausted")
+            raise PoolExhausted("cache pool exhausted")
         return self._free.pop()
 
     def free(self, slot: int) -> None:
@@ -527,7 +535,7 @@ class PagedCachePool:
                 + (" per shard" if self.n_shards > 1 else ""))
         plan = self._plan_admission(prompt_len, max_new_tokens, digests)
         if plan is None:
-            raise RuntimeError("paged cache pool exhausted")
+            raise PoolExhausted("paged cache pool exhausted")
         d, blks, matched, need = plan
         slot = self._free_slots_by_shard[d].pop()
         self._reserved_by_shard[d] += need
@@ -602,7 +610,7 @@ class PagedCachePool:
             self._deindex(blk)
             self.reclaimed_cached_blocks += 1
         else:
-            raise RuntimeError("paged cache pool out of blocks")
+            raise PoolExhausted("paged cache pool out of blocks")
         if self._slot_reserve.get(slot, 0) > 0:
             self._slot_reserve[slot] -= 1
             self._reserved_by_shard[d] -= 1

@@ -64,8 +64,8 @@ from repro.launch.steps import (get_serving_step, greedy_next_token,
                                 merge_first_tokens, nonfinite_rows,
                                 shadow_logit_mse)
 from repro.serve.cache_pool import (CachePool, PagedCachePool,
-                                    dense_slot_bytes, paged_block_bytes,
-                                    paged_slot_bytes)
+                                    PoolExhausted, dense_slot_bytes,
+                                    paged_block_bytes, paged_slot_bytes)
 from repro.serve.faults import InjectedFault, poison_logit_rows
 from repro.serve.scheduler import (DONE, PREFILLING, RUNNING, WAITING,
                                    Request, Scheduler)
@@ -668,7 +668,7 @@ class ContinuousBatchingEngine:
                     if self.faults is not None:
                         self.faults.on_alloc(slot)
                     pool.ensure_range(slot, start, start + take)
-                except (InjectedFault, RuntimeError):
+                except (InjectedFault, PoolExhausted):
                     alloc_failed.append(st)
                     continue
                 ok.append((slot, st, start, take))
@@ -1200,7 +1200,7 @@ class ContinuousBatchingEngine:
                                 if inj is not None:
                                     inj.on_alloc(slot)
                                 pool.ensure_block(slot, st.next_pos)
-                            except (InjectedFault, RuntimeError) as e:
+                            except (InjectedFault, PoolExhausted) as e:
                                 last_fault_error = e
                                 alloc_bad.append(st)
                     for st in alloc_bad:

@@ -179,6 +179,22 @@ def test_impossible_after_quarantine_fails_gracefully(model, params):
     assert pool.allocatable_blocks == 6 - pool.n_quarantined_blocks
 
 
+@pytest.mark.parametrize("method", ["ensure_range", "ensure_block"])
+def test_device_error_in_allocation_ends_the_drain(model, params, prompts,
+                                                   monkeypatch, method):
+    """Only pool exhaustion is contained as an allocation failure. A device
+    error there — an OOM, a failed compile of the COW block copy — is a
+    ``jax.errors.JaxRuntimeError``, itself a ``RuntimeError``, and must end
+    the drain instead of retiring requests ``failed``."""
+    def device_error(self, *a, **kw):
+        raise jax.errors.JaxRuntimeError("RESOURCE_EXHAUSTED: out of memory")
+
+    monkeypatch.setattr(PagedCachePool, method, device_error)
+    eng = ContinuousBatchingEngine(model, n_slots=4, max_len=32)
+    with pytest.raises(jax.errors.JaxRuntimeError, match="RESOURCE_EXHAUSTED"):
+        eng.serve(params, _requests(prompts))
+
+
 # ---------------------------------------------------------------------------
 # pool-level quarantine mechanics
 # ---------------------------------------------------------------------------

@@ -78,8 +78,8 @@ def test_kernel_matches_gather_reference(dtype, window, seed):
     n_pages, bs = 5, 4
     n_blocks, bt, lengths, poison, rng = _random_paged_case(
         seed, B=B, n_pages=n_pages, bs=bs, dtype=dtype)
-    k = _fill(rng, (n_blocks, bs, Hkv, Dk), dtype, poison)
-    v = _fill(rng, (n_blocks, bs, Hkv, Dk), dtype, poison)
+    k = _fill(rng, (n_blocks, Hkv, bs, Dk), dtype, poison)
+    v = _fill(rng, (n_blocks, Hkv, bs, Dk), dtype, poison)
     q = jnp.asarray(rng.normal(size=(B, Hkv, G, Dk)), jnp.bfloat16)
     kw = dict(window=window, scale=math.sqrt(Dk), scale_mode="div",
               score_dtype=jnp.bfloat16, probs_dtype=jnp.bfloat16,
@@ -111,8 +111,8 @@ def test_kernel_ignores_nan_in_unreferenced_blocks():
 
     def run(poison_value):
         r = np.random.default_rng(99)
-        k = _fill(r, (n_blocks, bs, Hkv, Dk), dtype, poison, poison_value)
-        v = _fill(r, (n_blocks, bs, Hkv, Dk), dtype, poison, poison_value)
+        k = _fill(r, (n_blocks, Hkv, bs, Dk), dtype, poison, poison_value)
+        v = _fill(r, (n_blocks, Hkv, bs, Dk), dtype, poison, poison_value)
         q = jnp.asarray(r.normal(size=(B, Hkv, G, Dk)), jnp.bfloat16)
         return np.asarray(paged_decode_attention(
             q, k, v, bt, lengths, interpret=True, **kw), np.float32)
@@ -137,8 +137,8 @@ def test_kernel_never_reads_past_length_exact_boundary():
         for pg in range(-(-int(lengths[b]) // bs)):
             bt[b, pg] = next(ids)
     bt = jnp.asarray(bt)
-    k = rng.normal(size=(n_blocks, bs, Hkv, Dk)).astype(np.float32)
-    v = rng.normal(size=(n_blocks, bs, Hkv, Dk)).astype(np.float32)
+    k = rng.normal(size=(n_blocks, Hkv, bs, Dk)).astype(np.float32)
+    v = rng.normal(size=(n_blocks, Hkv, bs, Dk)).astype(np.float32)
     q = jnp.asarray(rng.normal(size=(B, Hkv, G, Dk)), jnp.bfloat16)
     kw = dict(scale=math.sqrt(Dk), scale_mode="div",
               score_dtype=jnp.bfloat16, probs_dtype=jnp.bfloat16,
@@ -156,9 +156,62 @@ def test_kernel_never_reads_past_length_exact_boundary():
             pg, off = divmod(pos, bs)
             blk = int(bt[b, pg])
             if blk >= 0:
-                k2[blk, off] = 1e4
-                v2[blk, off] = -1e4
+                k2[blk, :, off] = 1e4
+                v2[blk, :, off] = -1e4
     np.testing.assert_array_equal(base, run(k2, v2))
+
+
+@pytest.mark.parametrize("window", [None, 5])
+def test_kernel_head_major_pages_match_dense_attention(window):
+    """The page layout itself, checked against attention over the logical
+    (B, S, Hkv, D) K/V — an oracle that shares no layout code with the
+    kernel or ``paged_decode_attention_ref``. Pages are scattered
+    head-major by hand, every head and token distinct; the last live page
+    carries a stale tail past the row's length, dead table entries point at
+    stale poisoned blocks or are -1 (clamped to a poisoned trash block 0),
+    and the window drops early keys. Blocks of 8 tokens, the smallest the
+    TPU compiler tiles."""
+    B, Hkv, G, D, n_pages, bs = 3, 2, 2, 16, 4, 8
+    rng = np.random.default_rng(11)
+    S = n_pages * bs
+    lengths = np.asarray([S, 13, 6], np.int32)
+    bf = lambda x: np.asarray(jnp.asarray(x, jnp.bfloat16), np.float32)
+    k_log = bf(rng.normal(size=(B, S, Hkv, D)))
+    v_log = bf(rng.normal(size=(B, S, Hkv, D)))
+    q = bf(rng.normal(size=(B, Hkv, G, D)))
+    n_live = B * n_pages
+    n_blocks = 1 + n_live + 2                 # trash, live, 2 stale
+    k_pages = np.full((n_blocks, Hkv, bs, D), POISON, np.float32)
+    v_pages = np.full((n_blocks, Hkv, bs, D), -POISON, np.float32)
+    tables = np.full((B, n_pages), -1, np.int32)
+    ids = iter(rng.permutation(np.arange(1, 1 + n_live)))
+    for b in range(B):
+        for pg in range(-(-int(lengths[b]) // bs)):
+            blk = tables[b, pg] = next(ids)
+            for i in range(bs):
+                if pg * bs + i < lengths[b]:      # the rest stays stale
+                    k_pages[blk, :, i] = k_log[b, pg * bs + i]
+                    v_pages[blk, :, i] = v_log[b, pg * bs + i]
+    tables[1, -1] = n_blocks - 1              # stale id past row 1's pages
+    kw = dict(window=window, scale=math.sqrt(D), scale_mode="div",
+              out_dtype=jnp.float32)
+    args = (jnp.asarray(q), jnp.asarray(k_pages, jnp.bfloat16),
+            jnp.asarray(v_pages, jnp.bfloat16), jnp.asarray(tables),
+            jnp.asarray(lengths))
+    got = np.asarray(paged_decode_attention(*args, interpret=True, **kw))
+
+    want = np.zeros((B, Hkv, G, D), np.float32)
+    for b in range(B):
+        ln = int(lengths[b])
+        lo = 0 if window is None else max(0, ln - window)
+        for h in range(Hkv):
+            s = q[b, h] @ k_log[b, lo:ln, h].T / math.sqrt(D)   # (G, keys)
+            p = np.exp(s - s.max(-1, keepdims=True))
+            want[b, h] = (p / p.sum(-1, keepdims=True)) @ v_log[b, lo:ln, h]
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(
+        got, np.asarray(ref.paged_decode_attention_ref(*args, **kw)),
+        rtol=1e-5, atol=1e-5)
 
 
 def test_kernel_mla_shape_and_scales():
@@ -169,8 +222,8 @@ def test_kernel_mla_shape_and_scales():
     n_pages, bs = 4, 4
     n_blocks, bt, lengths, poison, rng = _random_paged_case(
         7, B=B, n_pages=n_pages, bs=bs, dtype=jnp.bfloat16)
-    ckv = _fill(rng, (n_blocks, bs, 1, r), jnp.bfloat16, poison)
-    kr = _fill(rng, (n_blocks, bs, 1, dr), jnp.bfloat16, poison)
+    ckv = _fill(rng, (n_blocks, 1, bs, r), jnp.bfloat16, poison)
+    kr = _fill(rng, (n_blocks, 1, bs, dr), jnp.bfloat16, poison)
     q1 = jnp.asarray(rng.normal(size=(B, 1, H, r)), jnp.float32)
     q2 = jnp.asarray(rng.normal(size=(B, 1, H, dr)), jnp.float32)
     kw = dict(q2=q2, k2=kr, scale=1.0 / math.sqrt(r + dr), scale_mode="mul",
@@ -184,7 +237,7 @@ def test_kernel_mla_shape_and_scales():
                                rtol=1e-4, atol=1e-6)
 
     # fp8 cache with real per-tensor dequant scales
-    kq = _fill(rng, (16, bs, 1, r), jnp.float8_e4m3fn, [])
+    kq = _fill(rng, (16, 1, bs, r), jnp.float8_e4m3fn, [])
     btq = jnp.asarray(np.arange(1, 1 + B * n_pages).reshape(B, n_pages))
     ln = jnp.asarray([n_pages * bs, 3], jnp.int32)
     kw = dict(scale=math.sqrt(r), scale_mode="div", k_scale=0.25,
@@ -205,8 +258,8 @@ if HAS_HYPOTHESIS:
         Hkv, G, Dk = 2, 2, 16
         n_blocks, bt, lengths, poison, rng = _random_paged_case(
             seed, B=B, n_pages=n_pages, bs=bs, dtype=jnp.bfloat16)
-        k = _fill(rng, (n_blocks, bs, Hkv, Dk), jnp.bfloat16, poison)
-        v = _fill(rng, (n_blocks, bs, Hkv, Dk), jnp.bfloat16, poison)
+        k = _fill(rng, (n_blocks, Hkv, bs, Dk), jnp.bfloat16, poison)
+        v = _fill(rng, (n_blocks, Hkv, bs, Dk), jnp.bfloat16, poison)
         q = jnp.asarray(rng.normal(size=(B, Hkv, G, Dk)), jnp.bfloat16)
         kw = dict(window=window, scale=math.sqrt(Dk), scale_mode="div",
                   score_dtype=jnp.bfloat16, probs_dtype=jnp.bfloat16,
@@ -243,9 +296,9 @@ def _layer_attention_case(paged_attn, ctx=None, window=None, kv_scales=None):
                            * 0.05).astype(jnp.bfloat16)
     B, bs, n_pages = 2, 4, 4
     n_blocks = 1 + B * n_pages
-    cache = {"k": jnp.asarray(rng.normal(size=(n_blocks, bs, 2, 16)),
+    cache = {"k": jnp.asarray(rng.normal(size=(n_blocks, 2, bs, 16)),
                               jnp.bfloat16),
-             "v": jnp.asarray(rng.normal(size=(n_blocks, bs, 2, 16)),
+             "v": jnp.asarray(rng.normal(size=(n_blocks, 2, bs, 16)),
                               jnp.bfloat16)}
     bt = jnp.asarray(np.arange(1, 1 + B * n_pages).reshape(B, n_pages))
     x = jnp.asarray(rng.normal(size=(B, 1, 64)), jnp.bfloat16)
@@ -356,9 +409,9 @@ def test_paged_gather_applies_dequant_scales():
     the rounding point actually matters; absent/unit entries stay a plain
     upcast bit-identical to the legacy gather."""
     rng = np.random.default_rng(5)
-    cache = {"k": jnp.asarray(rng.normal(size=(7, 4, 2, 8)),
+    cache = {"k": jnp.asarray(rng.normal(size=(7, 2, 4, 8)),
                               jnp.float8_e4m3fn),
-             "v": jnp.asarray(rng.normal(size=(7, 4, 2, 8)),
+             "v": jnp.asarray(rng.normal(size=(7, 2, 4, 8)),
                               jnp.float8_e4m3fn)}
     bt = jnp.asarray([[1, 3, -1], [2, 6, 4]], jnp.int32)
     g, _ = L.paged_gather(cache, bt, jnp.bfloat16,
@@ -387,8 +440,8 @@ def test_mla_fused_rejects_nonunit_scales():
         rng.normal(size=(2 * (8 + 8), 8)) * 0.05, jnp.bfloat16)}}
     qn = jnp.asarray(rng.normal(size=(B, 1, 2, 8)), jnp.bfloat16)
     qr = jnp.asarray(rng.normal(size=(B, 1, 2, 4)), jnp.bfloat16)
-    cache = {"ckv": jnp.asarray(rng.normal(size=(5, bs, 8)), jnp.bfloat16),
-             "kr": jnp.asarray(rng.normal(size=(5, bs, 4)), jnp.bfloat16)}
+    cache = {"ckv": jnp.asarray(rng.normal(size=(5, 1, bs, 8)), jnp.bfloat16),
+             "kr": jnp.asarray(rng.normal(size=(5, 1, bs, 4)), jnp.bfloat16)}
     bt = jnp.asarray([[1, 2]], jnp.int32)
     pos = jnp.asarray([[5]], jnp.int32)
     with pytest.raises(ValueError, match="non-unit"):

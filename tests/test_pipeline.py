@@ -136,3 +136,33 @@ def test_wallclock_gain_model_additivity_interface(setup):
     gains = gm.gains(ops, combos)
     assert gains.shape == (2,)
     assert gains[0] == 0.0  # all-ref combo is zero by definition
+
+
+def test_calibrate_op_chunk_matches_one_pass(setup):
+    """``op_chunk`` only splits which ops carry probes per backward pass:
+    each op's sensitivity is the same as from one pass over all ops."""
+    from repro.core.pipeline import calibrate
+    m, params, batches, sens = setup
+    bundle = calibrate(m, params, batches[:1], gain_models={}, op_chunk=3)
+    one = calibrate_sensitivity(lambda p, b, c: m.loss(p, b, c), params,
+                                batches[:1])
+    assert len(sens.ops) > 3            # several chunks
+    assert bundle.sens.sensitivity.keys() == one.sensitivity.keys()
+    for name, s in one.sensitivity.items():
+        np.testing.assert_allclose(bundle.sens.sensitivity[name], s,
+                                   rtol=1e-5, err_msg=name)
+
+
+@pytest.mark.parametrize("kind", ["TPU v5 lite", "TPU v5e"])
+def test_profile_for_device_kind_is_the_default_profile(kind):
+    from repro.hw.profiles import TPU_V5E, profile_for_device_kind
+    assert profile_for_device_kind(kind) is TPU_V5E is AMPOptions().hw
+    # published v5e peaks are not marked modeled; fp8 rows are
+    assert {"bf16", "int8"}.isdisjoint(TPU_V5E.modeled)
+    assert "fp8_e4m3" in TPU_V5E.modeled
+
+
+def test_profile_for_unknown_device_kind_is_an_error():
+    from repro.hw.profiles import profile_for_device_kind
+    with pytest.raises(LookupError, match="no hardware profile"):
+        profile_for_device_kind("cpu")

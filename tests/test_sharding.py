@@ -121,7 +121,7 @@ def test_kv_page_spec_shards_blocks_and_heads():
     for name in ("k", "v"):
         ps = shd.partition_spec(specs[name], _Mesh2x2())
         # pages over data, kv heads over model; token dim replicated
-        assert ps == P("data", None, "model"), (name, ps)
+        assert ps == P("data", "model"), (name, ps)
 
 
 def test_kv_page_spec_gqa_head_fallback():
@@ -150,7 +150,7 @@ def test_kv_page_spec_block_count_fallback():
     cfg = AttnConfig(d_model=64, n_heads=4, n_kv_heads=2, d_head=16)
     ps = shd.partition_spec(kv_page_spec(cfg, n_blocks=7, block_size=4)["k"],
                             _Mesh2x2())
-    assert ps == P(None, None, "model")
+    assert ps == P(None, "model")
 
 
 def test_mla_page_spec_mesh_shardings():
@@ -160,7 +160,7 @@ def test_mla_page_spec_mesh_shardings():
     specs = mla_page_spec(cfg, n_blocks=8, block_size=4)
     ckv = shd.partition_spec(specs["ckv"], _Mesh2x2())
     kr = shd.partition_spec(specs["kr"], _Mesh2x2())
-    assert ckv == P("data", None, "model")   # latent rank over model
+    assert ckv == P("data", None, None, "model")  # latent rank over model
     assert kr == P("data")                   # rope dim replicated
 
     class Mesh3:
