@@ -69,6 +69,7 @@ from repro.serve.cache_pool import (CachePool, PagedCachePool,
 from repro.serve.faults import InjectedFault, poison_logit_rows
 from repro.serve.scheduler import (DONE, PREFILLING, RUNNING, WAITING,
                                    Request, Scheduler)
+from repro.serve.telemetry import NOSPAN, LiveCounters
 
 
 class _ImpossibleRequest(Exception):
@@ -324,6 +325,13 @@ class ContinuousBatchingEngine:
     resumed request re-prefills prompt + generated-so-far and continues;
     its tokens are identical to an uninterrupted run. At uniform priority
     nothing is ever evicted (pure FCFS backpressure, as before).
+
+    ``telemetry`` (a :class:`~repro.serve.telemetry.Telemetry`) records
+    each drain's host-loop spans — ticks, admission, input building, step
+    calls and waits on delivery — in memory and as profiler annotations;
+    with None (the default) no span is made. The counts of the drain in
+    progress (``live_counters``) are kept either way, and
+    ``ServeSummary.counters`` is built from them.
     """
 
     def __init__(self, model, n_slots: int = 4, max_len: int = 512,
@@ -334,7 +342,8 @@ class ContinuousBatchingEngine:
                  mesh=None, prefix_cache: Optional[bool] = None,
                  preemption: bool = True, prefill_cobatch: bool = True,
                  adaptive=None, faults=None, max_retries: int = 1,
-                 guardrail=None, kernel_fault_limit: int = 2):
+                 guardrail=None, kernel_fault_limit: int = 2,
+                 telemetry=None):
         if getattr(model, "cache_needs_enc_len", False):
             raise NotImplementedError(
                 "continuous batching currently serves decoder-only LMs")
@@ -462,6 +471,11 @@ class ContinuousBatchingEngine:
         self._ctl_lock = threading.Lock()
         self._cancel_pending: set = set()
         self._shutdown_flag = False
+        # spans of every drain (a repro.serve.telemetry.Telemetry), or None;
+        # the counters of the drain in progress (the last one after it ends)
+        # are kept either way
+        self.telemetry = telemetry
+        self.live_counters = None
 
     # ------------------------------------------------------------------
     def cancel(self, rid: int) -> None:
@@ -531,9 +545,10 @@ class ContinuousBatchingEngine:
         return st.digests
 
     def _admit(self, params, pool, sched: Scheduler, now: int,
-               evict=None, on_impossible=None) -> None:
+               evict=None, on_impossible=None) -> int:
         """Claim slots for admissible requests and emit prefill work items;
         no device work happens here — the step loop drives the chunks.
+        Returns the number of requests admitted.
 
         ``evict`` (paged + preemption) is the engine's eviction hook: when
         the best arrived waiter is gated on resources and outranks a live
@@ -559,6 +574,7 @@ class ContinuousBatchingEngine:
                     raise _ImpossibleRequest(st, need)
                 return pool.can_admit(plen, mnew,
                                       digests=self._digests(pool, st))
+        admitted = 0
         while True:
             while pool.n_free_slots:
                 try:
@@ -610,38 +626,34 @@ class ContinuousBatchingEngine:
                 else:
                     slot = pool.alloc()
                 sched.start_prefill(st, slot, now, start_at=start_at)
+                admitted += 1
                 if st.wall_admitted == 0.0:   # resumed: keep first admission
                     st.wall_admitted = time.perf_counter()
             if evict is None:
-                return
+                return admitted
             cand = sched.peek_admissible(now)
             if cand is None:
-                return
+                return admitted
             victim = sched.preempt_candidate(cand.request.priority)
             if victim is None:
-                return
+                return admitted
             if not evict(victim):
-                return
+                return admitted
 
-    def _prefill_tick(self, params, pool, sched: Scheduler, now: int):
-        """Run one compiled prefill-chunk step: co-batch the next chunk of
-        every prefilling slot — across buckets, padded to the largest one
-        (``prefill_cobatch``), or the legacy same-bucket-as-head group —
-        over the full ``n_slots`` batch (inactive rows pass through with
-        valid = 0). Chunk order is priority, then shortest remaining
+    def _prefill_rows(self, pool, sched: Scheduler):
+        """Host-side inputs of the next prefill-chunk step: co-batch the
+        next chunk of every prefilling slot — across buckets, padded to the
+        largest one (``prefill_cobatch``), or the legacy same-bucket-as-head
+        group — over the full ``n_slots`` batch (inactive rows pass through
+        with valid = 0). Chunk order is priority, then shortest remaining
         prefill.
 
-        Returns ``(dt, nxt_dev, flag_dev, finished, n_tokens,
-        alloc_failed)``: the step's dispatch wall time, the (n_slots,)
-        *device* greedy-token vector (no host readback — delivery is the
-        caller's job) plus its non-finite tripwire flag vector, the list of
-        ``(slot, state)`` pairs whose prompt completed this tick (their
-        next token is row ``slot`` of ``nxt_dev``; its ``out_tokens`` entry
-        holds a ``None`` placeholder until the value lands on the host),
-        the real prompt tokens processed, and the states whose page
-        allocation failed this tick (dropped from the step; the caller
-        contains them). ``nxt_dev`` is None when every candidate's
-        allocation failed — no step ran."""
+        Returns ``(items, bucket, tok, start, valid, alloc_failed)``: the
+        ``(slot, state, start, take)`` chunks of the step, its bucket width,
+        the (n_slots, bucket) token rows and the per-row start/valid
+        vectors, and the states whose page allocation failed (dropped from
+        the step; the caller contains them). ``items`` is empty when every
+        candidate's allocation failed."""
         cands = []
         for slot, st in sched.prefilling.items():
             start = st.prefill_pos
@@ -674,7 +686,7 @@ class ContinuousBatchingEngine:
                 ok.append((slot, st, start, take))
             cands = ok
             if not cands:
-                return 0.0, None, None, [], 0, alloc_failed
+                return [], None, None, None, None, alloc_failed
         if self.prefill_cobatch:
             # co-batch across buckets: pad every slot's chunk to the
             # largest bucket and run one step (per-row start/valid mask the
@@ -701,7 +713,41 @@ class ContinuousBatchingEngine:
                                           np.int32)[start:start + take]
             start_v[slot] = start
             valid_v[slot] = take
+        return items, bucket, tok, start_v, valid_v, alloc_failed
+
+    def _prefill_tick(self, params, pool, sched: Scheduler, now: int,
+                      cur_tok):
+        """Run one compiled prefill-chunk step over the rows
+        :meth:`_prefill_rows` builds, and scatter the first token of every
+        prompt it completes into the device-resident decode input
+        ``cur_tok``.
+
+        Returns ``(dt, seq, nxt_dev, flag_dev, cur_tok, finished,
+        alloc_failed)``: the step's dispatch wall time, its sequence number
+        among the drain's steps, the (n_slots,) *device* greedy-token vector
+        (no host readback — delivery is the caller's job) plus its
+        non-finite tripwire flag vector, the updated decode input, the list
+        of ``(slot, state)`` pairs whose prompt completed this tick (their
+        next token is row ``slot`` of ``nxt_dev``; its ``out_tokens`` entry
+        holds a ``None`` placeholder until the value lands on the host), and
+        the states whose page allocation failed this tick (dropped from the
+        step; the caller contains them). ``nxt_dev`` is None when every
+        candidate's allocation failed — no step ran."""
+        tel, live = self.telemetry, self.live_counters
+        with NOSPAN if tel is None else tel.span("serve.prepare"):
+            (items, bucket, tok, start_v, valid_v,
+             alloc_failed) = self._prefill_rows(pool, sched)
+        if not items:
+            return 0.0, None, None, None, cur_tok, [], alloc_failed
+        completes = np.zeros((self.n_slots,), bool)
+        for slot, st, start, take in items:
+            completes[slot] = start + take == st.effective_prompt_len
+        n_tokens = int(valid_v.sum())
+        seq = live.prefill_chunks + live.decode_steps
         t0 = time.perf_counter()
+        sp = None if tel is None else tel.open(
+            "serve.step_call", t0, kind="prefill", seq=seq, bucket=bucket,
+            tokens=n_tokens, rows=len(items), start=start_v, valid=valid_v)
         if self.paged:
             logits, pool.caches = self.prefill_chunk_step(
                 params, pool.caches, jnp.asarray(tok), jnp.asarray(start_v),
@@ -713,20 +759,27 @@ class ContinuousBatchingEngine:
         nxt_dev = greedy_next_token(logits)
         flag_dev = nonfinite_rows(logits)
         dt = time.perf_counter() - t0
+        if completes.any():
+            cur_tok = merge_first_tokens(cur_tok, nxt_dev,
+                                         jnp.asarray(completes))
+        if sp is not None:
+            tel.close(sp)
+        live.prefill_chunks += 1
+        live.prefill_tokens += n_tokens
         if self.paged and self.prefix_cache:
             # index the blocks this chunk filled (after dispatch: any
             # future matcher's chunks are dispatched later on the same
             # device stream, so they order after these writes)
-            for slot, st, start, take in items:
-                pool.register_prefix(slot, start + take)
+            with NOSPAN if tel is None else tel.span("serve.prepare"):
+                for slot, st, start, take in items:
+                    pool.register_prefix(slot, start + take)
         finished = []
-        n_prefill_tokens = sum(take for _, _, _, take in items)
         for slot, st, start, take in items:
             st = sched.prefill_advance(slot, take, dt)
             if st.prefill_pos == st.effective_prompt_len:
                 st = sched.finish_prefill(slot, None, now)
                 finished.append((slot, st))
-        return dt, nxt_dev, flag_dev, finished, n_prefill_tokens, alloc_failed
+        return dt, seq, nxt_dev, flag_dev, cur_tok, finished, alloc_failed
 
     def serve(self, params, requests: Sequence[Request], *,
               sync: bool = False,
@@ -778,13 +831,9 @@ class ContinuousBatchingEngine:
         # or to a row the next first-chunk prefill fully resets (dense).
         cur_tok = jnp.zeros((self.n_slots, 1), jnp.int32)
         now = 0
-        n_steps = 0
-        decode_s = 0.0
-        host_blocked_s = 0.0
-        drain_wait_s = 0.0
-        n_readbacks = 0
-        readback_sizes: list = []
-        inflight_peak = 0
+        tel = self.telemetry
+        live = self.live_counters = LiveCounters(pool, sched)
+        readback_max = inflight_peak = 0
         t_first_decode = None
         peak_queue = peak_live = peak_blocks = peak_slots = 0
         # per-decode-step attention HBM read model (paged): the fused kernel
@@ -793,9 +842,8 @@ class ContinuousBatchingEngine:
         # revisit block 0 and their copies are elided); the gather path
         # materializes every table slot of every row, so its traffic scales
         # with provisioned capacity
-        attn_pages_fused = attn_pages_gather = live_token_steps = 0
-        prefill_chunks = decode_stall_steps = max_stall_run = stall_run = 0
-        prefill_tokens = 0
+        attn_pages_fused = attn_pages_gather = 0
+        decode_stall_steps = max_stall_run = stall_run = 0
         stall_s_run = 0.0
         stall_s: list = []            # per-decode-step injected prefill time
         adaptive_swaps: list = []     # plan swaps applied this drain
@@ -877,7 +925,7 @@ class ContinuousBatchingEngine:
                     on_token(st.request.rid, idx, tok)
 
         def consume():
-            nonlocal n_readbacks
+            nonlocal readback_max
             stop = False
             while not stop:
                 item = q.get()
@@ -894,8 +942,9 @@ class ContinuousBatchingEngine:
                         break
                     batch.append(more)
                 arrs = jax.device_get([(tok, flg) for tok, flg, _ in batch])
-                n_readbacks += 1
-                readback_sizes.append(len(batch))
+                live.n_readbacks += 1
+                live.steps_read_back += len(batch)
+                readback_max = max(readback_max, len(batch))
                 for (_, _, dl), (arr, flg) in zip(batch, arrs):
                     try:
                         deliver(arr, flg, dl)
@@ -918,15 +967,26 @@ class ContinuousBatchingEngine:
                                         name="serve-consumer", daemon=True)
             consumer.start()
 
-        def emit(nxt_dev, flag_dev, deliveries):
-            nonlocal host_blocked_s, n_readbacks, inflight_peak
+        def emit(nxt_dev, flag_dev, deliveries, seq):
+            """Hand step ``seq``'s tokens to delivery."""
+            nonlocal readback_max, inflight_peak
+            t0 = time.perf_counter()
+            sp = None if tel is None else tel.open("serve.emit_wait", t0,
+                                                   seq=seq)
             if sync:
-                t0 = time.perf_counter()
                 arr = np.asarray(nxt_dev)   # blocks on the device step
                 flg = None if flag_dev is None else np.asarray(flag_dev)
-                host_blocked_s += time.perf_counter() - t0
-                n_readbacks += 1
-                readback_sizes.append(1)
+            else:
+                # blocks only at max_in_flight
+                q.put((nxt_dev, flag_dev, deliveries))
+            t1 = time.perf_counter()
+            if sp is not None:
+                tel.close(sp, t1)
+            live.emit_wait_s += t1 - t0
+            if sync:
+                live.n_readbacks += 1
+                live.steps_read_back += 1
+                readback_max = 1
                 try:
                     deliver(arr, flg, deliveries)
                 except BaseException as e:  # noqa: BLE001 — user on_token
@@ -935,10 +995,6 @@ class ContinuousBatchingEngine:
                     # settled and reconciled), re-raise after
                     consumer_err.append(e)
             else:
-                t0 = time.perf_counter()
-                # blocks only at max_in_flight
-                q.put((nxt_dev, flag_dev, deliveries))
-                host_blocked_s += time.perf_counter() - t0
                 inflight_peak = max(inflight_peak, q.qsize())
 
         # ---- preemption: evict a live slot back to the waiting queue ----
@@ -1095,9 +1151,23 @@ class ContinuousBatchingEngine:
                 if st is not None and st.status != DONE:
                     cancel_live(st, "cancelled", now)
 
+        def admit():
+            with NOSPAN if tel is None else tel.span("serve.admit") as sp:
+                n = self._admit(params, pool, sched, now,
+                                evict if self.preemption else None,
+                                on_impossible=impossible)
+                if sp is not None:
+                    sp.attrs["admitted"] = n
+
         t_start = time.perf_counter()
+        tick = None            # the open serve.tick span
         try:
             while True:
+                if tel is not None:
+                    # ticks tile the loop: each closes as the next opens
+                    if tick is not None:
+                        tel.close(tick)
+                    tick = tel.open("serve.tick", tick=now)
                 if not sched.has_work():
                     # drain-end pipeline flush: a tripwire flag still in
                     # flight can re-queue a retry — settle every in-flight
@@ -1117,9 +1187,7 @@ class ContinuousBatchingEngine:
                     continue
                 if self.adaptive is not None:
                     consult_adaptive()
-                self._admit(params, pool, sched, now,
-                            evict if self.preemption else None,
-                            on_impossible=impossible)
+                admit()
                 peak_queue = max(peak_queue, sched.queue_depth)
                 # prefill phase — TTFT-aware arbitration: prefill freely
                 # while nothing is decoding, else at most chunk_budget chunk
@@ -1134,9 +1202,9 @@ class ContinuousBatchingEngine:
                                 and inj.on_step("prefill") == "hung"):
                             kernel_faults += 1
                             maybe_degrade()
-                        (dt, nxt_dev, flag_dev, finished, n_tok,
+                        (dt, seq, nxt_dev, flag_dev, cur_tok, finished,
                          alloc_failed) = self._prefill_tick(
-                            params, pool, sched, now)
+                            params, pool, sched, now, cur_tok)
                     except InjectedFault as e:
                         # step blew up before any cache write: contain every
                         # prefilling slot (bounded retry re-prefills from
@@ -1154,8 +1222,6 @@ class ContinuousBatchingEngine:
                     if nxt_dev is None:     # every candidate's alloc failed
                         chunks_this_tick += 1
                         continue
-                    prefill_chunks += 1
-                    prefill_tokens += n_tok
                     chunks_this_tick += 1
                     if was_decoding:
                         decode_stall_steps += 1
@@ -1163,77 +1229,76 @@ class ContinuousBatchingEngine:
                         max_stall_run = max(max_stall_run, stall_run)
                         stall_s_run += dt
                     if finished:
-                        # scatter first tokens into the device-resident
-                        # decode input; ship the same vector to the host
-                        # for delivery
-                        mask = np.zeros((self.n_slots,), bool)
-                        deliveries = []
-                        for slot, st in finished:
-                            mask[slot] = True
-                            # resumed requests already hold delivered tokens;
-                            # the placeholder finish_prefill appended is the
-                            # last entry, not necessarily index 0
-                            deliveries.append(
-                                (st, len(st.out_tokens) - 1, slot))
-                        cur_tok = merge_first_tokens(cur_tok, nxt_dev,
-                                                     jnp.asarray(mask))
-                        emit(nxt_dev, flag_dev, deliveries)
+                        # the first tokens are in the device-resident decode
+                        # input already; ship the same vector to the host
+                        # for delivery. Resumed requests already hold
+                        # delivered tokens: the placeholder finish_prefill
+                        # appended is the last entry, not necessarily index 0
+                        deliveries = [(st, len(st.out_tokens) - 1, slot)
+                                      for slot, st in finished]
+                        emit(nxt_dev, flag_dev, deliveries, seq)
                         for slot, st in finished:
                             if st.done:          # max_new_tokens == 1
                                 retired.append(sched.retire(st, now))
                                 pool.free_slot(slot)
                     # a finished 1-token request frees its slot immediately:
                     # let a queued request claim it this same tick
-                    self._admit(params, pool, sched, now,
-                                evict if self.preemption else None,
-                                on_impossible=impossible)
+                    admit()
                 if sched.running:
-                    # fresh array every tick: jnp.asarray may be zero-copy
-                    # on CPU, and an in-flight step from a previous tick
-                    # could still alias a reused buffer we'd be zeroing
-                    pos_host = np.zeros((self.n_slots,), np.int32)
-                    alloc_bad = []
-                    for slot, st in sched.running.items():
-                        pos_host[slot] = st.next_pos
+                    with NOSPAN if tel is None else tel.span(
+                            "serve.prepare"):
+                        # fresh array every tick: jnp.asarray may be
+                        # zero-copy on CPU, and an in-flight step from a
+                        # previous tick could still alias a reused buffer
+                        # we'd be zeroing
+                        pos_host = np.zeros((self.n_slots,), np.int32)
+                        alloc_bad = []
+                        for slot, st in sched.running.items():
+                            pos_host[slot] = st.next_pos
+                            if self.paged:
+                                try:
+                                    if inj is not None:
+                                        inj.on_alloc(slot)
+                                    pool.ensure_block(slot, st.next_pos)
+                                except (InjectedFault, PoolExhausted) as e:
+                                    last_fault_error = e
+                                    alloc_bad.append(st)
+                        for st in alloc_bad:
+                            contain(st, kind="alloc_failure",
+                                    quarantine=False)
+                        if not sched.running:   # every page alloc failed
+                            now += 1
+                            continue
+                        # live tokens after this step: everything written so
+                        # far (next_pos) plus the write this step performs
+                        live_now = sum(st.next_pos + 1
+                                       for st in sched.running.values())
+                        peak_live = max(peak_live, live_now)
+                        peak_slots = max(peak_slots, len(sched.running))
                         if self.paged:
-                            try:
-                                if inj is not None:
-                                    inj.on_alloc(slot)
-                                pool.ensure_block(slot, st.next_pos)
-                            except (InjectedFault, PoolExhausted) as e:
-                                last_fault_error = e
-                                alloc_bad.append(st)
-                    for st in alloc_bad:
-                        contain(st, kind="alloc_failure", quarantine=False)
-                    if not sched.running:   # everyone's page alloc failed
-                        now += 1
-                        continue
-                    # live tokens after this step: everything written so far
-                    # (next_pos) plus the write this step performs
-                    live_now = sum(st.next_pos + 1
-                                   for st in sched.running.values())
-                    peak_live = max(peak_live, live_now)
-                    peak_slots = max(peak_slots, len(sched.running))
-                    if self.paged:
-                        peak_blocks = max(peak_blocks, pool.blocks_in_use)
-                        live_token_steps += live_now
-                        pages = {s: -(-(st.next_pos + 1) // pool.block_size)
-                                 for s, st in sched.running.items()}
-                        attn_pages_fused += sum(pages.values()) + sum(
-                            1 for s in range(self.n_slots)
-                            if pages.get(s, 0) < pool.max_blocks)
-                        attn_pages_gather += self.n_slots * pool.max_blocks
-                    bt = None
-                    if self.paged:
-                        # decode sees block tables only for *running* rows:
-                        # a slot mid-prefill owns real blocks, and the
-                        # vacant-row garbage write must go to the trash
-                        # block, not into K/V its earlier chunks wrote
-                        bt_host = pool.block_tables.copy()
-                        for s in range(self.n_slots):
-                            if s not in sched.running:
-                                bt_host[s] = -1
-                        bt = jnp.asarray(bt_host)
+                            peak_blocks = max(peak_blocks,
+                                              pool.blocks_in_use)
+                            live.decode_live_token_steps += live_now
+                            pages = {s: -(-(st.next_pos + 1)
+                                          // pool.block_size)
+                                     for s, st in sched.running.items()}
+                            attn_pages_fused += sum(pages.values()) + sum(
+                                1 for s in range(self.n_slots)
+                                if pages.get(s, 0) < pool.max_blocks)
+                            attn_pages_gather += (self.n_slots
+                                                  * pool.max_blocks)
+                        bt = None
+                        if self.paged:
+                            # decode sees block tables only for *running*
+                            # rows: a slot mid-prefill owns real blocks, and
+                            # the vacant-row garbage write must go to the
+                            # trash block, not into K/V its earlier chunks
+                            # wrote
+                            bt_host = pool.block_tables.copy()
+                            for s in range(self.n_slots):
+                                if s not in sched.running:
+                                    bt_host[s] = -1
+                            bt = jnp.asarray(bt_host)
                     # injected numeric poisons for this step: a NaN'd KV
                     # page is written *before* dispatch (the step reads it
                     # back through attention), a NaN'd logit row is applied
@@ -1246,11 +1311,11 @@ class ContinuousBatchingEngine:
                                     else slots[0])
                             if spec.kind == "nan_page" and self.paged:
                                 row = pool.block_tables[slot]
-                                live = [int(b) for b in row
+                                held = [int(b) for b in row
                                         if int(b) >= 0
                                         and int(b) % pool.blocks_per_shard]
-                                if live:
-                                    blk = live[min(spec.page, len(live) - 1)]
+                                if held:
+                                    blk = held[min(spec.page, len(held) - 1)]
                                     pool.poison_block(blk)
                                     poison_watch.add(slot)
                             else:   # nan_logits (nan_page degrades to it
@@ -1263,14 +1328,14 @@ class ContinuousBatchingEngine:
                     shadow = None
                     if (grail is not None and self.mp
                             and grail.restored_at is None
-                            and n_steps % grail.every == 0):
+                            and live.decode_steps % grail.every == 0):
                         # tau-anchored shadow: one high-precision decode
                         # step over the same inputs before the real step
                         # touches them (donate=False — its cache writes are
                         # discarded), MSE'd below against the active plan's
                         # logits for one sampled live row
                         rows = sorted(sched.running)
-                        grail_row = rows[(n_steps // grail.every)
+                        grail_row = rows[(live.decode_steps // grail.every)
                                          % len(rows)]
                         ref_step = get_serving_step(
                             self.model,
@@ -1288,9 +1353,15 @@ class ContinuousBatchingEngine:
                                 params, pool.caches, cur_tok,
                                 jnp.asarray(pos_host))
                         shadow = (s_logits, grail_row)
+                    seq = live.prefill_chunks + live.decode_steps
                     t0 = time.perf_counter()
                     if t_first_decode is None:
                         t_first_decode = t0
+                    sp = None if tel is None else tel.open(
+                        "serve.step_call", t0, kind="decode", seq=seq,
+                        bucket=1, tokens=len(sched.running),
+                        rows=len(sched.running),
+                        pos=pos_host[sorted(sched.running)])
                     try:
                         if (inj is not None
                                 and inj.on_step("decode") == "hung"):
@@ -1308,6 +1379,8 @@ class ContinuousBatchingEngine:
                         # the step never dispatched — caches are intact;
                         # contain every running request (bounded retry
                         # re-prefills prompt + tokens-so-far)
+                        if sp is not None:
+                            tel.close(sp)
                         last_fault_error = e
                         kernel_faults += 1
                         maybe_degrade()
@@ -1343,14 +1416,19 @@ class ContinuousBatchingEngine:
                     nxt_dev = greedy_next_token(logits)
                     flag_dev = nonfinite_rows(logits)
                     cur_tok = nxt_dev[:, None]
+                    if sp is not None:
+                        tel.close(sp)
+                    live.decode_steps += 1
+                    if self.paged:
+                        live.decode_capacity_token_steps += (
+                            self.n_slots * pool.max_blocks * pool.block_size)
                     deliveries = []
                     for slot in list(sched.running):
                         st = sched.running[slot]
                         deliveries.append((st, len(st.out_tokens), slot))
                         sched.record_token(slot, None)
-                    emit(nxt_dev, flag_dev, deliveries)
-                    decode_s += time.perf_counter() - t0
-                    n_steps += 1
+                    emit(nxt_dev, flag_dev, deliveries, seq)
+                    live.decode_s += time.perf_counter() - t0
                     stall_s.append(stall_s_run)
                     stall_s_run = 0.0
                     stall_run = 0
@@ -1381,6 +1459,8 @@ class ContinuousBatchingEngine:
                         break
                     now = max(now + 1, nxt_arrival)
         finally:
+            if tick is not None:
+                tel.close(tick)
             if consumer is not None:
                 # drain: everything emitted gets delivered before we return.
                 # Counted separately from host_blocked_s — this wait overlaps
@@ -1389,7 +1469,7 @@ class ContinuousBatchingEngine:
                 t0 = time.perf_counter()
                 q.put(None)
                 consumer.join()
-                drain_wait_s += time.perf_counter() - t0
+                live.drain_wait_s += time.perf_counter() - t0
 
         t_drain_end = time.perf_counter()
         total_s = t_drain_end - t_start
@@ -1401,6 +1481,7 @@ class ContinuousBatchingEngine:
                 # the books so the pool is reusable after the re-raise
                 pool.reconcile()
             raise consumer_err[0]
+        decode_s = live.decode_s
         if not sync and t_first_decode is not None:
             # async decode_s: the producer only measured dispatch time, so
             # report the wall span from the first decode dispatch to drain
@@ -1428,8 +1509,8 @@ class ContinuousBatchingEngine:
             "dense_kv_bytes": self.n_slots * dense_slot_bytes(self.model,
                                                               self.max_len),
             # chunked/bucketed prefill economics + decode-stall signals
-            "prefill_chunks": prefill_chunks,
-            "prefill_tokens": prefill_tokens,
+            "prefill_chunks": live.prefill_chunks,
+            "prefill_tokens": live.prefill_tokens,
             "prefill_cobatch": bool(self.prefill_cobatch),
             # priority scheduling: evictions back to the waiting queue
             "preemptions": sched.preemptions,
@@ -1443,13 +1524,14 @@ class ContinuousBatchingEngine:
             # drain is drain_wait_s, overlapping no dispatchable work), how
             # readbacks batched, and how far the device ran ahead of the host
             "sync": bool(sync),
-            "host_blocked_s": host_blocked_s,
-            "host_blocked_s_per_step": host_blocked_s / max(n_steps, 1),
-            "drain_wait_s": drain_wait_s,
-            "n_readbacks": n_readbacks,
-            "readback_batch_max": int(max(readback_sizes, default=0)),
-            "readback_batch_mean": (float(np.mean(readback_sizes))
-                                    if readback_sizes else 0.0),
+            "host_blocked_s": live.emit_wait_s,
+            "host_blocked_s_per_step": (live.emit_wait_s
+                                        / max(live.decode_steps, 1)),
+            "drain_wait_s": live.drain_wait_s,
+            "n_readbacks": live.n_readbacks,
+            "readback_batch_max": readback_max,
+            "readback_batch_mean": (live.steps_read_back / live.n_readbacks
+                                    if live.n_readbacks else 0.0),
             "steps_in_flight_peak": inflight_peak,
             "n_cancelled": sum(1 for st in retired
                                if st.result_status in ("cancelled",
@@ -1519,10 +1601,8 @@ class ContinuousBatchingEngine:
                     else attn_pages_gather) * blk_bytes,
                 decode_attn_bytes_fused_model=attn_pages_fused * blk_bytes,
                 decode_attn_bytes_gather_model=attn_pages_gather * blk_bytes,
-                decode_live_token_steps=live_token_steps,
-                decode_capacity_token_steps=(n_steps * self.n_slots
-                                             * pool.max_blocks
-                                             * pool.block_size),
+                decode_live_token_steps=live.decode_live_token_steps,
+                decode_capacity_token_steps=live.decode_capacity_token_steps,
                 # prefix cache economics: tokens whose prefill was skipped
                 # because a resident block chain already held them
                 prefix_cache=bool(self.prefix_cache),
@@ -1530,13 +1610,12 @@ class ContinuousBatchingEngine:
                 prefix_hit_blocks=pool.prefix_hit_blocks,
                 prefix_hit_tokens=pool.prefix_hit_tokens,
                 cow_forks=pool.cow_forks,
-                cached_blocks_final=pool.n_cached_blocks,
                 reclaimed_cached_blocks=pool.reclaimed_cached_blocks)
         else:
             counters["peak_kv_bytes"] = counters["dense_kv_bytes"]
         # throughput over the decode phase only: each request's first token
         # comes out of its prefill, whose wall time is accounted as TTFT
-        return ServeSummary(results=results, n_steps=n_steps,
+        return ServeSummary(results=results, n_steps=live.decode_steps,
                             decode_s=decode_s, total_s=total_s,
                             tokens_per_s=(n_decoded / decode_s
                                           if decode_s > 0 else 0.0),
