@@ -70,6 +70,13 @@ def paged_block_bytes(model, block_size: int) -> int:
             - param_bytes(model.paged_cache_specs(1, 1, block_size)))
 
 
+def page_widths(model, block_size: int) -> set:
+    """Last dims of the model's paged K/V entries (the leaves whose leading
+    axis is the pool's blocks)."""
+    return {s.shape[-1] for s in model.paged_cache_specs(
+        1, 1, block_size).values() if s.logical_axes[0] == "kv_blocks"}
+
+
 def paged_slot_bytes(model, block_size: int) -> int:
     """HBM bytes one *slot* pins under paging regardless of live tokens:
     the slot-major leaves (SSM state on mamba/hybrid archs; zero for pure

@@ -60,12 +60,14 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.mpconfig import as_assignment
+from repro.kernels.paged_attention import kernel_steps
 from repro.launch.steps import (get_serving_step, greedy_next_token,
                                 merge_first_tokens, nonfinite_rows,
                                 shadow_logit_mse)
 from repro.serve.cache_pool import (CachePool, PagedCachePool,
                                     PoolExhausted, dense_slot_bytes,
-                                    paged_block_bytes, paged_slot_bytes)
+                                    page_widths, paged_block_bytes,
+                                    paged_slot_bytes)
 from repro.serve.faults import InjectedFault, poison_logit_rows
 from repro.serve.scheduler import (DONE, PREFILLING, RUNNING, WAITING,
                                    Request, Scheduler)
@@ -837,12 +839,12 @@ class ContinuousBatchingEngine:
         t_first_decode = None
         peak_queue = peak_live = peak_blocks = peak_slots = 0
         # per-decode-step attention HBM read model (paged): the fused kernel
-        # fetches each running row's live pages (plus at most one trash-block
-        # fetch per row whose tail pages are dead — consecutive dead pages
-        # revisit block 0 and their copies are elided); the gather path
-        # materializes every table slot of every row, so its traffic scales
-        # with provisioned capacity
+        # copies each row's live pages once (``kernel_steps``); the gather
+        # path materializes every table slot of every row, so its traffic
+        # scales with provisioned capacity
         attn_pages_fused = attn_pages_gather = 0
+        kv_widths = (page_widths(self.model, pool.block_size) if self.paged
+                     else ())
         decode_stall_steps = max_stall_run = stall_run = 0
         stall_s_run = 0.0
         stall_s: list = []            # per-decode-step injected prefill time
@@ -1279,12 +1281,15 @@ class ContinuousBatchingEngine:
                             peak_blocks = max(peak_blocks,
                                               pool.blocks_in_use)
                             live.decode_live_token_steps += live_now
-                            pages = {s: -(-(st.next_pos + 1)
-                                          // pool.block_size)
-                                     for s, st in sched.running.items()}
-                            attn_pages_fused += sum(pages.values()) + sum(
-                                1 for s in range(self.n_slots)
-                                if pages.get(s, 0) < pool.max_blocks)
+                            # the kernel's view: vacant rows hold length 1
+                            steps, copy_steps, pages = kernel_steps(
+                                pos_host + 1, block_size=pool.block_size,
+                                n_pages=pool.max_blocks,
+                                widths=kv_widths)
+                            if self.paged_attn == "fused":
+                                live.decode_kernel_grid_steps += steps
+                                live.decode_kernel_copy_steps += copy_steps
+                            attn_pages_fused += pages
                             attn_pages_gather += (self.n_slots
                                                   * pool.max_blocks)
                         bt = None

@@ -62,6 +62,10 @@ class LiveCounters:
     decode_steps: int = 0
     decode_live_token_steps: int = 0
     decode_capacity_token_steps: int = 0
+    # steps of one layer's fused paged-decode kernel call, and those that
+    # copy a live page (``kernels.paged_attention.kernel_steps``)
+    decode_kernel_grid_steps: int = 0
+    decode_kernel_copy_steps: int = 0
     n_readbacks: int = 0
     steps_read_back: int = 0
     emit_wait_s: float = 0.0

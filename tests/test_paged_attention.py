@@ -16,7 +16,9 @@ import numpy as np
 import pytest
 
 from repro.kernels import ref
-from repro.kernels.paged_attention import paged_decode_attention
+from repro.kernels.paged_attention import (copies_in_loop, heads_per_step,
+                                           kernel_steps, pages_per_step,
+                                           paged_decode_attention)
 from repro.nn import layers as L
 from repro.quant.qops import QuantContext
 
@@ -28,15 +30,19 @@ except ImportError:          # pragma: no cover - exercised on minimal images
 
 
 def _random_paged_case(seed, *, B, n_pages, bs, dtype, vacant_row=True,
-                       stale_entries=True):
+                       stale_entries=True, lengths=()):
     """Cache + block tables with every hazard the pool can produce: rows of
     different lengths, unallocated (-1) entries, stale entries pointing at
     NaN-poisoned blocks, and a vacant row (all -1, length 1 — the engine's
-    garbage-row shape, which must read only the trash block 0)."""
+    garbage-row shape, which must read only the trash block 0). ``lengths``
+    fixes the first rows' lengths."""
     rng = np.random.default_rng(seed)
     live_budget = B * n_pages
     n_blocks = 1 + live_budget + 4          # trash + live + 4 poison blocks
-    lengths = rng.integers(1, n_pages * bs + 1, size=B).astype(np.int32)
+    lengths = np.concatenate([
+        np.asarray(lengths, np.int32),
+        rng.integers(1, n_pages * bs + 1, size=B - len(lengths))
+    ]).astype(np.int32)
     if vacant_row:
         lengths[-1] = 1
     perm = rng.permutation(np.arange(1, 1 + live_budget))
@@ -70,14 +76,82 @@ def _fill(rng, shape, dtype, poison_blocks, value=POISON):
     return jnp.asarray(x).astype(dtype)
 
 
-@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float8_e4m3fn])
-@pytest.mark.parametrize("window", [None, 7])
-@pytest.mark.parametrize("seed", [0, 1])
-def test_kernel_matches_gather_reference(dtype, window, seed):
-    B, Hkv, G, Dk = 3, 2, 2, 32
-    n_pages, bs = 5, 4
+# Kernel geometries: ``P = pages_per_step(bs, n_pages)`` pages per step.
+# "pages": one group (P = n_pages). "groups": 20 pages of 8 in groups of 16,
+# the last partial; rows of length 0, exactly one group (128), one token
+# past it (129) and the whole table, so a window of 7 skips whole groups.
+# "loop": the same with 128-lane pages, which the kernel copies itself in a
+# loop over the live groups (narrower pages come through the grid).
+# "heads": Hkv * G over 128 lanes, so each KV head gets its own grid cell.
+GEOMETRY = {
+    "pages": dict(B=3, Hkv=2, G=2, Dk=32, n_pages=5, bs=4, lengths=()),
+    "groups": dict(B=6, Hkv=2, G=2, Dk=32, n_pages=20, bs=8,
+                   lengths=(0, 128, 129, 160)),
+    "loop": dict(B=6, Hkv=2, G=2, Dk=128, n_pages=20, bs=8,
+                 lengths=(0, 128, 129, 160)),
+    "heads": dict(B=3, Hkv=2, G=72, Dk=16, n_pages=5, bs=8, lengths=()),
+}
+
+
+def test_kernel_steps_at_chat_shapes():
+    """The benchmark's chat cell: 32 rows, 2 KV heads of 128 in one grid
+    cell, a table of 256 pages of 16, about 1,245 live tokens a row. Its
+    128-lane pages are copied in a loop over the live groups of 8 pages:
+    one step per live group and phase, far under 2,048 per layer, each
+    copying; each live page is copied once for K and once for V. Pages of
+    64 lanes come through a grid of 32 rows x 2 phases x 32 groups."""
+    assert pages_per_step(16, 256) == 8 and heads_per_step(2, 8) == 2
+    assert copies_in_loop([128]) and not copies_in_loop([128, 64])
+    # the geometries above reach a partial last group and heads in the grid
+    g = GEOMETRY["groups"]
+    assert pages_per_step(g["bs"], g["n_pages"]) == 16 and g["n_pages"] == 20
+    assert heads_per_step(2, GEOMETRY["heads"]["G"]) == 1
+    assert heads_per_step(2, GEOMETRY["pages"]["G"]) == 2
+    lengths = np.random.default_rng(0).integers(64, 2432, 32)
+    groups = -(-lengths // 128)
+    steps, copy_steps, pages = kernel_steps(lengths, block_size=16,
+                                            n_pages=256, widths=[128])
+    assert steps == copy_steps == 2 * groups.sum() <= 2048
+    assert pages == (-(-lengths // 16)).sum()
+    assert kernel_steps(lengths, block_size=16, n_pages=256,
+                        widths=[64]) == (2048, copy_steps, pages)
+    # a row of length 0 copies nothing; a window skips whole groups
+    assert kernel_steps([0], block_size=16, n_pages=256,
+                        widths=[128]) == (1, 0, 0)
+    assert kernel_steps([0], block_size=16, n_pages=256,
+                        widths=[64]) == (64, 0, 0)
+    assert kernel_steps([300], block_size=16, n_pages=256, widths=[128],
+                        window=40) == (2, 2, 3)
+
+
+def _reference_cases():
+    """Seeds 0 and 1 in one group of pages, bf16 and fp8 caches; the
+    "groups", "loop" and "heads" geometries with bf16 caches. (With an fp8
+    cache, the "groups" case at seed 2 has one output element outside the
+    tolerance: a bf16 score rounds the other way after the gather oracle's
+    dot sums in another order, as it does for the one-page-per-step kernel
+    of earlier versions; fp8 over several groups is checked with f32
+    scores in test_kernel_mla_shape_and_scales.)"""
+    names = {jnp.bfloat16: "bfloat16", jnp.float8_e4m3fn: "float8_e4m3fn"}
+    for seed, tag, geometry, dtypes in (
+            (0, "0", "pages", names), (1, "1", "pages", names),
+            (2, "groups", "groups", [jnp.bfloat16]),
+            (2, "loop", "loop", [jnp.bfloat16]),
+            (3, "heads", "heads", [jnp.bfloat16])):
+        for window in (None, 7):
+            for dtype in dtypes:
+                yield pytest.param(dtype, window, seed, geometry,
+                                   id=f"{tag}-{window}-{names[dtype]}")
+
+
+@pytest.mark.parametrize("dtype,window,seed,geometry", _reference_cases())
+def test_kernel_matches_gather_reference(dtype, window, seed, geometry):
+    g = GEOMETRY[geometry]
+    B, Hkv, G, Dk = g["B"], g["Hkv"], g["G"], g["Dk"]
+    n_pages, bs = g["n_pages"], g["bs"]
     n_blocks, bt, lengths, poison, rng = _random_paged_case(
-        seed, B=B, n_pages=n_pages, bs=bs, dtype=dtype)
+        seed, B=B, n_pages=n_pages, bs=bs, dtype=dtype,
+        lengths=g["lengths"])
     k = _fill(rng, (n_blocks, Hkv, bs, Dk), dtype, poison)
     v = _fill(rng, (n_blocks, Hkv, bs, Dk), dtype, poison)
     q = jnp.asarray(rng.normal(size=(B, Hkv, G, Dk)), jnp.bfloat16)
@@ -100,26 +174,34 @@ def test_kernel_ignores_nan_in_unreferenced_blocks():
     entries point at: with NaN in those blocks, its output is finite and
     bit-identical to the same case with them zeroed. (The gather reference
     cannot pass this — it materializes every table slot and 0 * NaN = NaN —
-    which is exactly the hazard the in-kernel clamp removes.)"""
+    which is exactly the hazard the in-kernel clamp removes.) Both
+    geometries: in "groups" the dead pages of a partial group sit beside
+    live ones in the step that copies them, and a window skips groups."""
     dtype = jnp.bfloat16
-    B, Hkv, G, Dk, n_pages, bs = 3, 2, 2, 32, 5, 4
-    n_blocks, bt, lengths, poison, rng = _random_paged_case(
-        2, B=B, n_pages=n_pages, bs=bs, dtype=dtype)
-    kw = dict(scale=math.sqrt(Dk), scale_mode="div",
-              score_dtype=jnp.bfloat16, probs_dtype=jnp.bfloat16,
-              out_dtype=jnp.bfloat16)
+    for geometry, window in (("pages", None), ("groups", None),
+                             ("groups", 7), ("loop", None), ("loop", 7)):
+        g = GEOMETRY[geometry]
+        B, Hkv, G, Dk = g["B"], g["Hkv"], g["G"], g["Dk"]
+        n_pages, bs = g["n_pages"], g["bs"]
+        n_blocks, bt, lengths, poison, rng = _random_paged_case(
+            2, B=B, n_pages=n_pages, bs=bs, dtype=dtype,
+            lengths=g["lengths"])
+        kw = dict(window=window, scale=math.sqrt(Dk), scale_mode="div",
+                  score_dtype=jnp.bfloat16, probs_dtype=jnp.bfloat16,
+                  out_dtype=jnp.bfloat16)
 
-    def run(poison_value):
-        r = np.random.default_rng(99)
-        k = _fill(r, (n_blocks, Hkv, bs, Dk), dtype, poison, poison_value)
-        v = _fill(r, (n_blocks, Hkv, bs, Dk), dtype, poison, poison_value)
-        q = jnp.asarray(r.normal(size=(B, Hkv, G, Dk)), jnp.bfloat16)
-        return np.asarray(paged_decode_attention(
-            q, k, v, bt, lengths, interpret=True, **kw), np.float32)
+        def run(poison_value):
+            r = np.random.default_rng(99)
+            k = _fill(r, (n_blocks, Hkv, bs, Dk), dtype, poison, poison_value)
+            v = _fill(r, (n_blocks, Hkv, bs, Dk), dtype, poison, poison_value)
+            q = jnp.asarray(r.normal(size=(B, Hkv, G, Dk)), jnp.bfloat16)
+            return np.asarray(paged_decode_attention(
+                q, k, v, bt, lengths, interpret=True, **kw), np.float32)
 
-    with_nan = run(np.nan)
-    assert np.all(np.isfinite(with_nan)), "kernel fetched a stale/dead block"
-    np.testing.assert_array_equal(with_nan, run(0.0))
+        with_nan = run(np.nan)
+        assert np.all(np.isfinite(with_nan)), \
+            f"kernel fetched a stale/dead block ({geometry}, {window})"
+        np.testing.assert_array_equal(with_nan, run(0.0))
 
 
 def test_kernel_never_reads_past_length_exact_boundary():
@@ -217,36 +299,44 @@ def test_kernel_head_major_pages_match_dense_attention(window):
 def test_kernel_mla_shape_and_scales():
     """MLA-absorbed shape: Hkv=1, H query heads, rope second operand,
     v = k (latent), multiplied scale, f32 all the way. Plus the fp8
-    per-tensor dequant scales path (k_scale/v_scale != 1)."""
-    B, H, r, dr = 2, 4, 24, 8
-    n_pages, bs = 4, 4
-    n_blocks, bt, lengths, poison, rng = _random_paged_case(
-        7, B=B, n_pages=n_pages, bs=bs, dtype=jnp.bfloat16)
-    ckv = _fill(rng, (n_blocks, 1, bs, r), jnp.bfloat16, poison)
-    kr = _fill(rng, (n_blocks, 1, bs, dr), jnp.bfloat16, poison)
-    q1 = jnp.asarray(rng.normal(size=(B, 1, H, r)), jnp.float32)
-    q2 = jnp.asarray(rng.normal(size=(B, 1, H, dr)), jnp.float32)
-    kw = dict(q2=q2, k2=kr, scale=1.0 / math.sqrt(r + dr), scale_mode="mul",
-              out_dtype=jnp.float32)
-    got = paged_decode_attention(q1, ckv, None, bt, lengths, interpret=True,
-                                 **kw)
-    want = ref.paged_decode_attention_ref(q1, ckv, None, bt, lengths, **kw)
-    got = np.asarray(got, np.float32)
-    assert np.all(np.isfinite(got))
-    np.testing.assert_allclose(got, np.asarray(want, np.float32),
-                               rtol=1e-4, atol=1e-6)
+    per-tensor dequant scales path (k_scale/v_scale != 1). In one group
+    of pages, and over partial groups with 24-lane latents (through the
+    grid) and 128-lane ones (copied in the kernel's loop)."""
+    B, H = 2, 4
+    for n_pages, bs, fixed, r, dr in ((4, 4, (), 24, 8),
+                                      (20, 8, (129,), 24, 8),
+                                      (20, 8, (129,), 128, 128)):
+        n_blocks, bt, lengths, poison, rng = _random_paged_case(
+            7, B=B, n_pages=n_pages, bs=bs, dtype=jnp.bfloat16,
+            lengths=fixed)
+        ckv = _fill(rng, (n_blocks, 1, bs, r), jnp.bfloat16, poison)
+        kr = _fill(rng, (n_blocks, 1, bs, dr), jnp.bfloat16, poison)
+        q1 = jnp.asarray(rng.normal(size=(B, 1, H, r)), jnp.float32)
+        q2 = jnp.asarray(rng.normal(size=(B, 1, H, dr)), jnp.float32)
+        kw = dict(q2=q2, k2=kr, scale=1.0 / math.sqrt(r + dr),
+                  scale_mode="mul", out_dtype=jnp.float32)
+        got = paged_decode_attention(q1, ckv, None, bt, lengths,
+                                     interpret=True, **kw)
+        want = ref.paged_decode_attention_ref(q1, ckv, None, bt, lengths,
+                                              **kw)
+        got = np.asarray(got, np.float32)
+        assert np.all(np.isfinite(got))
+        np.testing.assert_allclose(got, np.asarray(want, np.float32),
+                                   rtol=1e-4, atol=1e-6)
 
-    # fp8 cache with real per-tensor dequant scales
-    kq = _fill(rng, (16, 1, bs, r), jnp.float8_e4m3fn, [])
-    btq = jnp.asarray(np.arange(1, 1 + B * n_pages).reshape(B, n_pages))
-    ln = jnp.asarray([n_pages * bs, 3], jnp.int32)
-    kw = dict(scale=math.sqrt(r), scale_mode="div", k_scale=0.25,
-              v_scale=2.0, out_dtype=jnp.float32)
-    got = paged_decode_attention(q1, kq, None, btq, ln, interpret=True, **kw)
-    want = ref.paged_decode_attention_ref(q1, kq, None, btq, ln, **kw)
-    np.testing.assert_allclose(np.asarray(got, np.float32),
-                               np.asarray(want, np.float32),
-                               rtol=1e-4, atol=1e-6)
+        # fp8 cache with real per-tensor dequant scales
+        kq = _fill(rng, (max(16, 1 + B * n_pages), 1, bs, r),
+                   jnp.float8_e4m3fn, [])
+        btq = jnp.asarray(np.arange(1, 1 + B * n_pages).reshape(B, n_pages))
+        ln = jnp.asarray([n_pages * bs, 3], jnp.int32)
+        kw = dict(scale=math.sqrt(r), scale_mode="div", k_scale=0.25,
+                  v_scale=2.0, out_dtype=jnp.float32)
+        got = paged_decode_attention(q1, kq, None, btq, ln, interpret=True,
+                                     **kw)
+        want = ref.paged_decode_attention_ref(q1, kq, None, btq, ln, **kw)
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(want, np.float32),
+                                   rtol=1e-4, atol=1e-6)
 
 
 if HAS_HYPOTHESIS:
@@ -279,8 +369,9 @@ if HAS_HYPOTHESIS:
 # ---------------------------------------------------------------------------
 
 
-def _layer_attention_case(paged_attn, ctx=None, window=None, kv_scales=None):
-    cfg = L.AttnConfig(d_model=64, n_heads=4, n_kv_heads=2, d_head=16,
+def _layer_attention_case(paged_attn, ctx=None, window=None, kv_scales=None,
+                          n_pages=4, positions=((9,), (4,)), d_head=16):
+    cfg = L.AttnConfig(d_model=64, n_heads=4, n_kv_heads=2, d_head=d_head,
                        window=window, kv_dequant_scales=kv_scales)
     rng = np.random.default_rng(11)
     specs = L.attn_specs("attn", cfg)
@@ -294,15 +385,15 @@ def _layer_attention_case(paged_attn, ctx=None, window=None, kv_scales=None):
             node = node.setdefault(q, {})
         node[parts[-1]] = (jax.random.normal(sub, ps.shape, jnp.float32)
                            * 0.05).astype(jnp.bfloat16)
-    B, bs, n_pages = 2, 4, 4
+    B, bs = 2, 4
     n_blocks = 1 + B * n_pages
-    cache = {"k": jnp.asarray(rng.normal(size=(n_blocks, 2, bs, 16)),
+    cache = {"k": jnp.asarray(rng.normal(size=(n_blocks, 2, bs, d_head)),
                               jnp.bfloat16),
-             "v": jnp.asarray(rng.normal(size=(n_blocks, 2, bs, 16)),
+             "v": jnp.asarray(rng.normal(size=(n_blocks, 2, bs, d_head)),
                               jnp.bfloat16)}
     bt = jnp.asarray(np.arange(1, 1 + B * n_pages).reshape(B, n_pages))
     x = jnp.asarray(rng.normal(size=(B, 1, 64)), jnp.bfloat16)
-    positions = jnp.asarray([[9], [4]], jnp.int32)
+    positions = jnp.asarray(positions, jnp.int32)
     ctx = ctx or QuantContext()
     y, new_cache = L.attention(params, ctx, "attn", cfg, x, positions,
                                cache=cache, cache_pos=positions[:, 0],
@@ -310,10 +401,22 @@ def _layer_attention_case(paged_attn, ctx=None, window=None, kv_scales=None):
     return np.asarray(y, np.float32), new_cache
 
 
-@pytest.mark.parametrize("window", [None, 6])
-def test_layer_fused_matches_gather(window):
-    yf, cf = _layer_attention_case("fused", window=window)
-    yg, cg = _layer_attention_case("gather", window=window)
+# "groups": 40 pages of 4 in groups of 32; one row one token past the first
+# group, the other exactly one group long; a window of 6 skips group 0.
+# "loop": the same with heads of 128, whose pages the kernel copies itself.
+GROUPS = dict(n_pages=40, positions=((128,), (127,)))
+GROUPS_6 = dict(n_pages=40, positions=((140,), (127,)))
+
+
+@pytest.mark.parametrize("window,table", [
+    pytest.param(None, {}, id="None"), pytest.param(6, {}, id="6"),
+    pytest.param(None, GROUPS, id="groups-None"),
+    pytest.param(6, GROUPS_6, id="groups-6"),
+    pytest.param(None, dict(GROUPS, d_head=128), id="loop-None"),
+    pytest.param(6, dict(GROUPS_6, d_head=128), id="loop-6")])
+def test_layer_fused_matches_gather(window, table):
+    yf, cf = _layer_attention_case("fused", window=window, **table)
+    yg, cg = _layer_attention_case("gather", window=window, **table)
     np.testing.assert_array_equal(yf, yg)
     for name in ("k", "v"):                  # identical cache writes too
         np.testing.assert_array_equal(np.asarray(cf[name], np.float32),

@@ -228,3 +228,40 @@ def test_sync_decode_time_counts_delivery(model, params):
     assert n_decoded > 0
     assert slow.decode_s - plain.decode_s >= 0.5 * nap * n_decoded
     assert slow.tokens_per_s < plain.tokens_per_s
+
+
+def test_kernel_step_counters_follow_the_decode_lengths(model, params):
+    """``decode_kernel_grid_steps`` and ``decode_kernel_copy_steps``: per
+    decode step, one layer's kernel runs 2 phases x ceil(n_pages / P) grid
+    steps a row, and copies in the steps of each row's groups that hold a
+    live page; vacant rows read one page (length 1). Counted by hand from
+    the positions each decode step call records, and the kernel's P."""
+    from repro.kernels.paged_attention import pages_per_step
+    n_slots, block, max_len = 3, 8, 320
+    tel = Telemetry()
+    eng = ContinuousBatchingEngine(model, n_slots=n_slots, max_len=max_len,
+                                   block_size=block, chunk_len=64,
+                                   telemetry=tel)
+    rng = np.random.default_rng(3)
+    reqs = [Request(rid=i, tokens=rng.integers(0, 500, n).astype(np.int32),
+                    max_new_tokens=12, arrival=i)
+            for i, n in enumerate((120, 126, 140, 60))]
+    eng.serve(params, reqs)
+    end = eng.live_counters.snapshot()
+    n_pages = max_len // block
+    P = pages_per_step(block, n_pages)
+    span = P * block
+    assert (P, -(-n_pages // P)) == (16, 3)
+    steps = copies = 0
+    crossed = False
+    for sp in tel.spans:
+        if sp.name != "serve.step_call" or sp.attrs["kind"] != "decode":
+            continue
+        lengths = [int(p) + 1 for p in sp.attrs["pos"]]
+        lengths += [1] * (n_slots - len(lengths))
+        crossed |= max(lengths) > span
+        steps += n_slots * 2 * -(-n_pages // P)
+        copies += sum(2 * -(-n // span) for n in lengths)
+    assert crossed, "no row reached a second group of pages"
+    assert end["decode_kernel_grid_steps"] == steps > 0
+    assert end["decode_kernel_copy_steps"] == copies

@@ -56,8 +56,8 @@ def _compile(fn, *args):
 
 
 def _paged_args(sharding, *, kv_dtype, block, heads, width, q_heads,
-                q_dtype=jnp.bfloat16):
-    n_pages = MAX_LEN // block
+                q_dtype=jnp.bfloat16, max_len=MAX_LEN):
+    n_pages = max_len // block
     n_blocks = 1 + BATCH * n_pages
     s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=sharding)
     return dict(
@@ -82,6 +82,41 @@ def test_paged_decode_compiles_llama3_1b(one_chip, kv_dtype, block):
             out_dtype=jnp.bfloat16, interpret=False)
 
     _compile(step, a["q"], a["pages"], a["pages"], a["bt"], a["lengths"])
+
+
+def test_paged_decode_compiles_llama3_1b_longest(one_chip):
+    """The longest ``max_len`` the kernel with one page per grid step
+    compiled at under the default scoped VMEM (32720 at these shapes;
+    32736 was refused): grouping pages must not lower it."""
+    hkv = LLAMA.n_kv_heads
+    a = _paged_args(one_chip, kv_dtype=jnp.bfloat16, block=16, heads=hkv,
+                    width=LLAMA.d_head, q_heads=LLAMA.n_heads // hkv,
+                    max_len=32720)
+
+    def step(q, k, v, bt, lengths):
+        return paged_decode_attention(
+            q, k, v, bt, lengths, scale=math.sqrt(LLAMA.d_head),
+            score_dtype=jnp.bfloat16, probs_dtype=jnp.bfloat16,
+            out_dtype=jnp.bfloat16, interpret=False)
+
+    _compile(step, a["q"], a["pages"], a["pages"], a["bt"], a["lengths"])
+
+
+def test_paged_decode_compiles_at_benchmark_shapes(one_chip):
+    """The benchmark's chat cell (Qwen2.5-3B): 32 rows, 2 KV heads of 128
+    with 8 query heads each, a table of 256 pages of 16, bf16."""
+    B, hkv, G, D, block, n_pages = 32, 2, 8, 128, 16, 256
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    pages = s((1 + B * n_pages, hkv, block, D), jnp.bfloat16)
+
+    def step(q, k, v, bt, lengths):
+        return paged_decode_attention(
+            q, k, v, bt, lengths, scale=math.sqrt(D),
+            score_dtype=jnp.bfloat16, probs_dtype=jnp.bfloat16,
+            out_dtype=jnp.bfloat16, interpret=False)
+
+    _compile(step, s((B, hkv, G, D), jnp.bfloat16), pages, pages,
+             s((B, n_pages), jnp.int32), s((B,), jnp.int32))
 
 
 def test_paged_decode_compiles_mla_absorbed(one_chip):
