@@ -1,71 +1,32 @@
-"""Operations and bytes the served model needs, computed from its published
-sizes (the keys of a configuration file, as in its ``config.json``).
+"""Operations and bytes the served model needs, by model family: each
+family's counts live in ``bench/costs/<model_type>.py``, found by the
+``model_type`` of the configuration file.
 
 Model FLOPs count each multiply-accumulate as two operations, and only the
 work the model needs: one pass of every weight matrix per processed token,
 attention over the live context (causal: a token at position ``p`` attends
 ``p + 1`` keys), and the output head once for each row that yields a logit.
 Padding, vacant batch rows and recomputation are not counted.
+
+A cost module gives, for a configuration ``cfg``:
+
+* ``prefill_row_flops(cfg, start, valid)``: one prompt chunk of ``valid``
+  tokens starting at position ``start``;
+* ``decode_row_flops(cfg, position)``: one token decoded at ``position``;
+* ``paged_attention_layer_cost(cfg, lengths, block_size, kv_bytes=2)``:
+  (FLOPs, HBM bytes) of the fused paged-decode kernel for one layer of one
+  decode step over rows of live ``lengths``.
+
+A family with no cost module has no counts: the metrics that need them are
+left out of the result line, never reported as 0 or as another family's
+count.
 """
 from __future__ import annotations
 
-import math
+from bench.harness import family_module
 
 
-def head_dim(cfg: dict) -> int:
-    return int(cfg.get("head_dim") or
-               cfg["hidden_size"] // cfg["num_attention_heads"])
-
-
-def linear_flops_per_token(cfg: dict) -> float:
-    """Weight-matrix FLOPs of one token through every layer (head excluded)."""
-    d, ff = cfg["hidden_size"], cfg["intermediate_size"]
-    h, hkv, dh = (cfg["num_attention_heads"], cfg["num_key_value_heads"],
-                  head_dim(cfg))
-    per_layer = d * h * dh + 2 * d * hkv * dh + h * dh * d + 3 * d * ff
-    return 2.0 * cfg["num_hidden_layers"] * per_layer
-
-
-def head_flops(cfg: dict) -> float:
-    return 2.0 * cfg["hidden_size"] * cfg["vocab_size"]
-
-
-def attention_flops(cfg: dict, keys: float) -> float:
-    """Scores and weighted values of one query token over ``keys`` keys, in
-    every layer."""
-    return (4.0 * cfg["num_hidden_layers"] * cfg["num_attention_heads"]
-            * head_dim(cfg) * keys)
-
-
-def prefill_row_flops(cfg: dict, start: int, valid: int) -> float:
-    """One prompt chunk of ``valid`` tokens starting at position ``start``."""
-    if valid <= 0:
-        return 0.0
-    keys = valid * start + valid * (valid + 1) / 2.0
-    return (valid * linear_flops_per_token(cfg) + attention_flops(cfg, keys)
-            + head_flops(cfg))
-
-
-def decode_row_flops(cfg: dict, position: int) -> float:
-    """One decoded token written at ``position`` (it attends position + 1
-    keys)."""
-    return (linear_flops_per_token(cfg) + attention_flops(cfg, position + 1)
-            + head_flops(cfg))
-
-
-def paged_attention_layer_cost(cfg: dict, lengths, block_size: int,
-                               kv_bytes: int = 2) -> tuple:
-    """(FLOPs, HBM bytes) of the fused paged-decode kernel for one layer of
-    one decode step, over rows whose live lengths are ``lengths``. Bytes
-    are the live K and V pages read (whole blocks), the query and the
-    output; the block table and lengths are negligible."""
-    h, hkv, dh = (cfg["num_attention_heads"], cfg["num_key_value_heads"],
-                  head_dim(cfg))
-    flops = 0.0
-    nbytes = 0.0
-    for n in lengths:
-        flops += 4.0 * h * dh * n
-        pages = math.ceil(n / block_size)
-        nbytes += 2 * pages * block_size * hkv * dh * kv_bytes
-        nbytes += 2 * h * dh * 2          # bf16 query in, output out
-    return flops, nbytes
+def for_config(cfg: dict):
+    """The cost module of the configuration's family, or None where the
+    family has none."""
+    return family_module("costs", cfg)

@@ -7,11 +7,33 @@ The entry the window drives is ``repro.serve.ContinuousBatchingEngine.serve``,
 built as ``repro.launch.serve --continuous`` builds it: paged KV pool, fused
 Pallas paged-decode kernel, chunked prefill, prefix cache, and in an AMP
 configuration the solved plan passed as ``mp=``.
+
+Adding a configuration takes new files only, each found by a name:
+
+* ``bench/configs/<config>.json``: the model's published ``config.json``,
+  key for key, with any key cut to size listed under ``reduced`` in
+  ``BENCHMARK.json``, plus ``reference`` (the module below), ``weight_seed``,
+  ``served_dtype``, ``deployment`` (what the cut stands for) and, to serve
+  under a plan, ``amp``;
+* ``bench/configs/<reference>.py``: the plain reference, which imports
+  nothing of the program: ``param_layout(cfg)`` (path -> shape, dtype, init
+  kind; checked against the served model's parameters) and
+  ``logits_at(weights, cfg, ids, rows, t_pad)``, computed in blocks so that
+  it fits beside the served weights;
+* for a family (``model_type``) not served yet, ``bench/models/<model_type>.py``
+  with ``lm_config(name, cfg)``, the program's model configuration for the
+  file, refusing by name each key it cannot serve; and
+  ``bench/costs/<model_type>.py``, its step costs (``bench/flops.py``);
+* ``bench/traffic/<mix>.json``: the mix (``bench/traffic.py``);
+* ``bench/limits/<workload>.json``: the limits of ``correct``;
+* ``bench/metrics/<metric>.py``: a reader for each new metric;
+* the ``configs``, ``workloads`` and metric entries in ``BENCHMARK.json``.
 """
 from __future__ import annotations
 
 import dataclasses
 import gc
+import importlib
 import importlib.util
 import json
 import math
@@ -181,24 +203,42 @@ def engine_counters(summary) -> dict:
     return out
 
 
+def family_module(package: str, cfg: dict):
+    """``bench/<package>/<model_type>.py`` for the configuration's family,
+    or None where there is none."""
+    family = str(cfg.get("model_type", ""))
+    name = f"bench.{package}.{family}"
+    if not family.isidentifier() or importlib.util.find_spec(name) is None:
+        return None
+    return importlib.import_module(name)
+
+
 def lm_config(name: str, cfg: dict):
-    """The program's model configuration for a configuration file."""
-    from repro.models.lm import LMConfig
-    if cfg.get("use_sliding_window"):
-        raise ValueError("sliding-window Qwen2 layers are not served here")
-    if float(cfg["rms_norm_eps"]) != 1e-6:
-        raise ValueError("the served model's RMSNorm epsilon is 1e-6")
-    if cfg["hidden_act"] != "silu":
-        raise ValueError(f"hidden_act {cfg['hidden_act']!r}")
-    d, h = cfg["hidden_size"], cfg["num_attention_heads"]
-    return LMConfig(name=name, n_layers=cfg["num_hidden_layers"],
-                    d_model=d, vocab_size=cfg["vocab_size"], n_heads=h,
-                    n_kv_heads=cfg["num_key_value_heads"],
-                    d_head=cfg.get("head_dim") or d // h,
-                    d_ff=cfg["intermediate_size"], qkv_bias=True,
-                    activation="swiglu", norm="rmsnorm",
-                    rope_theta=float(cfg["rope_theta"]),
-                    tie_embeddings=bool(cfg["tie_word_embeddings"]))
+    """The program's model configuration for a configuration file, by its
+    family's module ``bench/models/<model_type>.py``."""
+    family = family_module("models", cfg)
+    if family is None:
+        raise ValueError(f"model_type {cfg.get('model_type')!r}: no "
+                         f"bench/models/<model_type>.py serves it")
+    return family.lm_config(name, cfg)
+
+
+def served_model(name: str, cfg: dict, reference) -> tuple:
+    """(model, weights) of a configuration: the program's model, and the
+    reference's weight layout, checked against the model's parameters and
+    made from the file's ``weight_seed``."""
+    from repro.models.registry import build_model
+    from repro.nn.spec import flatten_paths
+    model = build_model(lm_config(name, cfg))
+    layout = reference.param_layout(cfg)
+    want = {p: (tuple(s.shape), str(s.dtype)) for p, s in
+            flatten_paths(model.abstract_params()).items()}
+    have = {p: (tuple(v[0]), v[1]) for p, v in layout.items()}
+    if want != have:
+        diff = sorted(set(want.items()) ^ set(have.items()))[:6]
+        raise ValueError(f"reference layout differs from the served "
+                         f"model's parameters: {diff}")
+    return model, make_weights(layout, int(cfg["weight_seed"]))
 
 
 def calibration_batches(vocab: int, amp: dict) -> list:
@@ -234,17 +274,14 @@ class Server:
 
     def __init__(self, config_name: str, config: dict, mix: dict,
                  clock: CompileClock, reference=None):
-        from repro.models.registry import build_model
         self.config_name, self.config, self.mix = config_name, config, mix
         self.clock = clock
         self.ref = reference or load_module(
             BENCH / "configs" / f"{config['reference']}.py")
         self.parts: dict = {}
         t = time.perf_counter()
-        self.model = build_model(lm_config(config_name, config))
-        layout = self.ref.param_layout(config)
-        self._check_layout(layout)
-        self.params = make_weights(layout, int(config["weight_seed"]))
+        self.model, self.params = served_model(config_name, config,
+                                               self.ref)
         jax.block_until_ready(self.params)
         self.parts["weights_s"] = time.perf_counter() - t
         self.plan, self.plan_info = None, None
@@ -256,16 +293,6 @@ class Server:
         self.engine = self.make_engine()
         self.warm_up()
         self.parts["warmup_s"] = time.perf_counter() - t
-
-    def _check_layout(self, layout: dict) -> None:
-        from repro.nn.spec import flatten_paths
-        want = {p: (tuple(s.shape), str(s.dtype)) for p, s in
-                flatten_paths(self.model.abstract_params()).items()}
-        have = {p: (tuple(v[0]), v[1]) for p, v in layout.items()}
-        if want != have:
-            diff = sorted(set(want.items()) ^ set(have.items()))[:6]
-            raise ValueError(f"reference layout differs from the served "
-                             f"model's parameters: {diff}")
 
     def _solve_plan(self, amp: dict):
         from repro.core.pipeline import AMPOptions, calibrate

@@ -38,13 +38,13 @@ def program_ms(run, contains: str):
     return 1e3 * statistics.fmean(e.dur for e in evs)
 
 
-def mean_step_flops(run) -> tuple:
+def mean_step_flops(run, costs) -> tuple:
     """(mean model FLOPs of one decode step, of one prefill step) over the
-    steps dispatched in the window."""
+    steps dispatched in the window, counted by the family's ``costs``."""
     cfg = run.config
-    dec = [sum(flops.decode_row_flops(cfg, int(p)) for p in pos)
+    dec = [sum(costs.decode_row_flops(cfg, int(p)) for p in pos)
            for _, pos in run.steps.decode]
-    pre = [sum(flops.prefill_row_flops(cfg, int(s), int(v))
+    pre = [sum(costs.prefill_row_flops(cfg, int(s), int(v))
                for s, v in zip(start, valid) if v > 0)
            for _, _, start, valid in run.steps.prefill]
     return (statistics.fmean(dec) if dec else 0.0,
@@ -55,13 +55,14 @@ def step_mfu(run):
     """Model FLOPs of the steps that ran in the traced stretch over its
     length times the chip's peak: executions counted from the trace, each
     weighted with the mean FLOPs of its kind of step in the window."""
-    if run.trace is None or run.steps is None:
+    costs = flops.for_config(run.config)
+    if costs is None or run.trace is None or run.steps is None:
         return None
     n_dec = len(tr.module_events(run.trace, DECODE_PROGRAM))
     n_pre = len(tr.module_events(run.trace, PREFILL_PROGRAM))
     if not n_dec and not n_pre:
         return None
-    f_dec, f_pre = mean_step_flops(run)
+    f_dec, f_pre = mean_step_flops(run, costs)
     lo, hi = run.trace_window
     n_dev = len(run.trace.devices)
     return share(n_dec * f_dec + n_pre * f_pre,
@@ -73,7 +74,9 @@ def paged_attn_roofline(run):
     chip's peaks, the larger of its FLOPs and its bytes bound, averaged over
     the decode steps of the window, over the kernel's mean device time per
     call in the trace."""
-    if run.trace is None or run.steps is None or not run.steps.decode:
+    costs = flops.for_config(run.config)
+    if (costs is None or run.trace is None or run.steps is None
+            or not run.steps.decode):
         return None
     calls = tr.op_events(run.trace, PAGED_KERNEL_OP)
     if not calls:
@@ -81,7 +84,7 @@ def paged_attn_roofline(run):
     bs = run.mix["server"]["block_size"]
     least = []
     for _, pos in run.steps.decode:
-        f, b = flops.paged_attention_layer_cost(run.config,
+        f, b = costs.paged_attention_layer_cost(run.config,
                                                 [int(p) + 1 for p in pos], bs)
         least.append(max(f / run.peaks.bf16_flops,
                          b / run.peaks.hbm_bytes_per_s))

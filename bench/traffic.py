@@ -26,9 +26,14 @@ stand for requests already being served: ranked by answer length, the r-th
 keeps ``ceil(answer * (r + 1) / n_slots)`` tokens, so slots start freeing
 at the steady rate from the first step.
 
-With ``system_prompts.cached_at_start`` the system prompts are served once
-before serving starts (set-up), as a deployment that has served them
-before holds them in its prefix cache.
+A "system prompt" is any prefix that sessions share: a system prompt, or a
+document that is asked about several times. Session ``s`` opens with
+prefix ``s % count``. ``system_prompts.tokens`` is a length, the same for
+every prefix, or a length distribution as ``user_tokens`` is: prefix ``i``
+then takes the length at quantile ``(i + 0.5) / count``, so the mix alone
+fixes the lengths here too. With ``system_prompts.cached_at_start`` the
+prefixes are served once before serving starts (set-up), as a deployment
+that has served them before holds them in its prefix cache.
 """
 from __future__ import annotations
 
@@ -57,6 +62,15 @@ def length_at(level: float, dist: dict) -> int:
     return int(min(max(round(x), dist["min"]), dist["max"]))
 
 
+def prefix_lengths(mix: dict) -> list:
+    """Token length of each shared prefix (``system_prompts``)."""
+    sys_cfg = mix["system_prompts"]
+    n, tokens = sys_cfg["count"], sys_cfg["tokens"]
+    if isinstance(tokens, dict):
+        return [length_at((i + 0.5) / n, tokens) for i in range(n)]
+    return [int(tokens)] * n
+
+
 def session_plan(mix: dict) -> list:
     """Per session: (system prompt index or -1, [(user, answer), ...]).
     Depends on the mix only."""
@@ -66,13 +80,14 @@ def session_plan(mix: dict) -> list:
         if math.gcd(s, k) != 1:
             raise ValueError(f"stride {s} must be coprime with "
                              f"quantile_block {k}")
-    n_sys = mix["system_prompts"]["count"]
-    sys_len = mix["system_prompts"]["tokens"]
+    sys_lens = prefix_lengths(mix)
+    n_sys = len(sys_lens)
     max_len = mix["server"]["max_len"]
     turns = mix["turns"]
     plan, j = [], 0
     for s in range(mix["sessions"]):
-        history = sys_len if n_sys else 0
+        sys_idx = s % n_sys if n_sys else -1
+        history = sys_lens[sys_idx] if n_sys else 0
         pairs = []
         for _ in range(turns[s % len(turns)]):
             u = length_at(((j * stride_u) % k + 0.5) / k, mix["user_tokens"])
@@ -83,7 +98,7 @@ def session_plan(mix: dict) -> list:
                 break
             pairs.append((u, a))
             history += u + a
-        plan.append((s % n_sys if n_sys else -1, pairs))
+        plan.append((sys_idx, pairs))
     return plan
 
 
@@ -114,10 +129,9 @@ def _queue(mix: dict, plan: list) -> list:
 
 
 def system_prompts(mix: dict, vocab: int, seed: int) -> list:
-    sys_cfg = mix["system_prompts"]
     return [np.random.default_rng([seed, 0, i]).integers(
-        0, vocab, sys_cfg["tokens"], dtype=np.int32)
-        for i in range(sys_cfg["count"])]
+        0, vocab, n, dtype=np.int32)
+        for i, n in enumerate(prefix_lengths(mix))]
 
 
 def generate(mix: dict, vocab: int, seed: int) -> list:
